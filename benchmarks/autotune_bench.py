@@ -30,6 +30,7 @@ import time
 
 from repro.autotune import (AutoTuner, ReplayScenario, TunerConfig,
                             clear_deployments, replay, serving_space)
+from repro.launch.entry import start
 
 COLS = (("a", 48), ("b", 64), ("c", 32))
 VIDS = ((0,), (0, 1), (1, 2), (0, 1, 2))
@@ -194,6 +195,7 @@ def run(rows: int = 1500, n: int = 160, seed: int = 0, trials: int = 12,
 
 
 def main() -> None:
+    start()  # compile cache + platform check
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=1500)
     ap.add_argument("--n", type=int, default=160)

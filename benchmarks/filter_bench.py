@@ -34,6 +34,7 @@ from repro.filter.attributes import synth_attributes
 from repro.index.registry import IndexStore
 from repro.launch.roofline import modeled_scan_bytes
 from repro.serve.engine import BatchEngine
+from repro.launch.entry import start
 
 COLS = [("a", 48), ("b", 64)]
 VIDS = [(0,), (0, 1), (1,)]
@@ -178,6 +179,7 @@ def run(rows: int = 4000, n_queries: int = 9, k: int = 10, seed: int = 0,
 
 
 def main() -> None:
+    start()  # compile cache + platform check
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=4000)
     ap.add_argument("--n", type=int, default=9)

@@ -48,6 +48,7 @@ from repro.data.vectors import make_database, make_queries
 from repro.ingest import CompactionPolicy, IngestConfig, IngestRuntime
 from repro.online import RuntimeConfig, churn_trace, row_batch
 from repro.online.trace import TimedMutation, TimedQuery
+from repro.launch.entry import start
 
 COLS = [("a", 48), ("b", 64), ("c", 32)]
 VIDS = [(0,), (0, 1), (1, 2), (0, 1, 2)]
@@ -352,6 +353,7 @@ def drift_retune(db, n, seed):
 
 
 def main():
+    start()  # compile cache + platform check
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=4000)
     ap.add_argument("--n", type=int, default=240)

@@ -25,6 +25,7 @@ import numpy as np
 from repro.kernels.streaming.ops import streaming_fused_scan
 from repro.kernels.streaming.ref import streaming_fused_scan_ref
 from repro.launch.roofline import VMEM_BYTES, streaming_vs_twopass
+from repro.launch.entry import start
 
 
 def _parity_spot_check(seed: int = 0) -> dict:
@@ -63,6 +64,7 @@ def run(quick: bool = False, out: str = "BENCH_kernels.json",
 
 
 def main() -> None:
+    start()  # compile cache + platform check
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--no-measure", action="store_true",

@@ -38,6 +38,7 @@ from repro.online import (OnlineRuntime, RuntimeConfig, burst_trace,
                           diurnal_trace, hot_item_trace, steady_trace,
                           tenant_skew_trace)
 from repro.tenancy import MultiTenantRuntime, Tenant
+from repro.launch.entry import start
 
 
 def vid_workload(db, vids, k, seed):
@@ -410,6 +411,7 @@ def run(rows: int = 10000, steady_n: int = 120, drift_n: int = 180,
 
 
 def main() -> None:
+    start()  # compile cache + platform check
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=10000)
     ap.add_argument("--steady-n", type=int, default=120)

@@ -117,8 +117,9 @@ def bench_case_study(n_rows: int = 30000, seed: int = 0):
 
 
 def bench_kernels():
-    """Kernel micro-bench (interpret mode on CPU: correctness-mode timing —
-    TPU perf comes from the roofline analysis, not these numbers)."""
+    """Kernel micro-bench on the platform ``launch.entry.start`` chose: the
+    TPU, or interpret mode on an explicit CPU run (correctness-mode
+    timing — never a device number)."""
     import jax
     import jax.numpy as jnp
     from repro.kernels.distance.kernel import batched_scores
@@ -130,7 +131,7 @@ def bench_kernels():
     q = jax.random.normal(key, (64, 128), jnp.float32)
     db = jax.random.normal(key, (4096, 128), jnp.float32)
     for name, fn in [
-        ("distance_pallas", lambda: batched_scores(q, db, interpret=True)),
+        ("distance_pallas", lambda: batched_scores(q, db)),
         ("distance_ref", lambda: batched_scores_ref(q, db)),
     ]:
         fn()
@@ -139,14 +140,13 @@ def bench_kernels():
             jax.block_until_ready(fn())
         log(f"kernels/{name}", (time.time() - t0) / 3 * 1e6, "64x4096x128")
     scores = jax.random.normal(key, (64, 4096), jnp.float32)
-    topk_scores(scores, 100, interpret=True)
+    topk_scores(scores, 100)
     t0 = time.time()
-    jax.block_until_ready(topk_scores(scores, 100, interpret=True))
+    jax.block_until_ready(topk_scores(scores, 100))
     log("kernels/topk_pallas", (time.time() - t0) * 1e6, "k=100")
     qa = jax.random.normal(key, (1, 4, 256, 64), jnp.float32)
-    flash_attention(qa, qa, qa, interpret=True, bq=64, bkv=64)
+    flash_attention(qa, qa, qa, bq=64, bkv=64)
     t0 = time.time()
-    jax.block_until_ready(flash_attention(qa, qa, qa, interpret=True,
-                                          bq=64, bkv=64))
+    jax.block_until_ready(flash_attention(qa, qa, qa, bq=64, bkv=64))
     log("kernels/flash_attention_pallas", (time.time() - t0) * 1e6,
         "B1H4S256d64")

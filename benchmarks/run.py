@@ -2,8 +2,11 @@
 import argparse
 import time
 
+from repro.launch.entry import start
+
 
 def main() -> None:
+    start()  # compile cache + platform check
     ap = argparse.ArgumentParser()
     ap.add_argument("--n-rows", type=int, default=30000,
                     help="database rows (paper: 1M in C++; see scale note)")

@@ -24,6 +24,7 @@ from repro.data.vectors import make_database, make_queries, make_workload
 from repro.index.registry import IndexStore
 from repro.serve.compiler import compile_batch, dispatch_plan
 from repro.serve.engine import BatchEngine
+from repro.launch.entry import start
 
 
 def _percentiles(lat_ms: list[float]) -> dict:
@@ -71,6 +72,7 @@ def bench(pairs, engine_factory, reps: int, batched: bool) -> dict:
 
 
 def main() -> None:
+    start()  # compile cache + platform check
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=12000)
     ap.add_argument("--reps", type=int, default=3)

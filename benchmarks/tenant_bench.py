@@ -29,6 +29,7 @@ from repro.data.vectors import make_database, make_queries
 from repro.online import RuntimeConfig, tenant_skew_trace
 from repro.serve.columnstore import ColumnStore
 from repro.tenancy import MultiTenantRuntime, Tenant
+from repro.launch.entry import start
 
 
 def _wl(db, vids, k, seed):
@@ -142,6 +143,7 @@ def efficiency_experiment(rows, k) -> dict:
 
 
 def main() -> None:
+    start()  # compile cache + platform check
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=1000)
     ap.add_argument("--k", type=int, default=10)

@@ -18,9 +18,11 @@ from repro.data.vectors import make_database, make_queries
 from repro.ingest import CompactionPolicy, IngestConfig, IngestRuntime
 from repro.online import RuntimeConfig, churn_trace
 from repro.online.trace import TimedMutation
+from repro.launch.entry import start
 
 
 def main():
+    start()  # compile cache + platform check
     cols = [("image", 64), ("title", 48), ("content", 64)]
     db = make_database(4000, cols, seed=2)
     drift_db = make_database(4000, cols, seed=77, spread=2.5, correlation=0.1)

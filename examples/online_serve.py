@@ -14,9 +14,11 @@ from repro.core.types import Constraints, Workload
 from repro.core.tuner import Mint
 from repro.data.vectors import make_database, make_queries
 from repro.online import OnlineRuntime, RuntimeConfig, diurnal_trace, steady_trace
+from repro.launch.entry import start
 
 
 def main():
+    start()  # compile cache + platform check
     db = make_database(5000, [("image", 64), ("title", 48), ("audio", 80),
                               ("content", 64)], seed=2)
     day_qs = make_queries(db, [(0,), (0, 1), (1,)], k=10, seed=0)

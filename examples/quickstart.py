@@ -8,9 +8,11 @@ from repro.core.types import Constraints, config_name
 from repro.core.tuner import Mint, execute_workload, ground_truth_cache
 from repro.data.vectors import make_database, make_workload
 from repro.index.registry import IndexStore
+from repro.launch.entry import start
 
 
 def main():
+    start()  # compile cache + platform check
     # a 4-column multi-modal database (e.g. image/title/description/content)
     db = make_database(12000, [("image", 128), ("title", 96),
                                ("description", 160), ("content", 192)], seed=0)

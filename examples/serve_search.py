@@ -17,9 +17,11 @@ from repro.data.vectors import make_database, make_queries, make_workload
 from repro.index.registry import IndexStore
 from repro.serve.compiler import dispatch_plan, compile_batch
 from repro.serve.engine import BatchEngine
+from repro.launch.entry import start
 
 
 def main():
+    start()  # compile cache + platform check
     db = make_database(3000, [("text", 128), ("image", 128), ("audio", 96)],
                        seed=1)
     workload = make_workload(db, "naive", k=20, seed=1)

@@ -8,9 +8,11 @@ import shutil
 
 from repro.configs.base import get_arch
 from repro.train.loop import TrainConfig, train
+from repro.launch.entry import start
 
 
 def main():
+    start()  # compile cache + platform check
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-moe-1b-a400m")
     ap.add_argument("--steps", type=int, default=60)

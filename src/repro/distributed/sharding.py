@@ -52,12 +52,12 @@ def _dp_axes(mesh: Mesh):
 
 @contextlib.contextmanager
 def use_mesh(mesh: Mesh):
+    """Make ``mesh`` the thread's logical-sharding mesh (``shard_act``
+    reads it). Callers still enter ``mesh`` itself around ``jax.jit``."""
     prev = getattr(_STATE, "mesh", None)
     _STATE.mesh = mesh
     try:
-        with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") \
-                else contextlib.nullcontext():
-            yield
+        yield
     finally:
         _STATE.mesh = prev
 

@@ -25,14 +25,22 @@ def _scan_gathered(sub: np.ndarray, qvec: np.ndarray, ek: int,
     backend this is ONE ``streaming_fused_scan`` dispatch (distance +
     online top-k, no (1, m) score vector round-tripped through host numpy);
     on CPU/interpret the numpy argpartition path is kept — it is faster
-    than a Python-interpreted Pallas grid and bit-stable for the tests."""
+    than a Python-interpreted Pallas grid and bit-stable for the tests.
+
+    The kernel sees the union padded to a power-of-two row bucket with the
+    true row count as its traced ``valid_n``: probe unions differ in size
+    per query and per ek, and an unbucketed shape compiles once each."""
     if use_kernel is None:
         use_kernel = not default_interpret()
-    ek = min(ek, sub.shape[0])
+    m = sub.shape[0]
+    ek = min(ek, m)
     if use_kernel:
         from repro.kernels.streaming.ops import streaming_fused_scan
+        padded = np.zeros((max(128, 1 << (m - 1).bit_length()), sub.shape[1]),
+                          dtype=np.float32)
+        padded[:m] = sub
         vals, idx = streaming_fused_scan(
-            jnp.asarray(qvec[None, :]), jnp.asarray(sub), k=ek)
+            jnp.asarray(qvec[None, :]), jnp.asarray(padded), k=ek, valid_n=m)
         return np.asarray(idx[0], dtype=np.int64), np.asarray(vals[0])
     scores = sub @ qvec
     part = np.argpartition(-scores, ek - 1)[:ek]

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import default_interpret, pad_to, tpu_compiler_params
+from repro.kernels.common import EXACT, default_interpret, pad_to
 
 
 def _distance_kernel(q_ref, db_ref, qsq_ref, dbsq_ref, out_ref, acc_ref, *,
@@ -31,7 +31,8 @@ def _distance_kernel(q_ref, db_ref, qsq_ref, dbsq_ref, out_ref, acc_ref, *,
     q = q_ref[...].astype(jnp.float32)
     db = db_ref[...].astype(jnp.float32)
     acc_ref[...] += jax.lax.dot_general(
-        q, db, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        q, db, (((1,), (1,)), ((), ())), precision=EXACT,
+        preferred_element_type=jnp.float32)
 
     @pl.when(kb == n_k_blocks - 1)
     def _epilogue():
@@ -81,7 +82,7 @@ def batched_scores(q: jnp.ndarray, db: jnp.ndarray, metric: str = "dot",
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Bp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qp, dbp, qsqp, dbsqp)

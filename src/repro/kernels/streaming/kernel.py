@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import EXACT
 from repro.kernels.topk.kernel import NEG_INF
 
 
@@ -71,7 +72,8 @@ def streaming_kernel(*refs, n_base_tiles: int, n_k_blocks: int, bn: int,
     if has_delta:
         db = jnp.where(in_base, db, dlt_ref[...].astype(jnp.float32))
     acc_ref[...] += jax.lax.dot_general(
-        q, db, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        q, db, (((1,), (1,)), ((), ())), precision=EXACT,
+        preferred_element_type=jnp.float32)
 
     @pl.when(kb == n_k_blocks - 1)
     def _fold_tile():

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import default_interpret, pad_to, tpu_compiler_params
+from repro.kernels.common import default_interpret, pad_to
 from repro.kernels.streaming.kernel import streaming_kernel
 
 
@@ -144,7 +144,7 @@ def streaming_fused_scan(q: jnp.ndarray, db: jnp.ndarray, k: int,
             jax.ShapeDtypeStruct((Bp, k_eff), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(*operands)

@@ -15,8 +15,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import default_interpret, pad_to, tpu_compiler_params
+from repro.kernels.common import default_interpret, pad_to
 
 NEG_INF = float(-3.0e38)
 
@@ -99,7 +100,7 @@ def topk_scores(scores: jnp.ndarray, k: int, bm: int = 128, bn: int = 512,
             jax.ShapeDtypeStruct((Bp, k_eff), jnp.float32),
             jax.ShapeDtypeStruct((Bp, k_eff), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(sp)

@@ -28,7 +28,7 @@ from __future__ import annotations
 import json
 
 from repro.configs.base import SHAPES, ArchConfig, get_arch
-from repro.launch.roofline import HW
+from repro.launch.roofline import V5E, peaks
 
 
 def geometry(cfg: ArchConfig) -> dict:
@@ -111,12 +111,13 @@ def analytic_cell(arch: str, shape_name: str, mesh: str, n_params: int,
         bloc = B / min(dp, B)
         coll = 4 * L * bloc * D * 2 + bloc * V * 4
 
-    t_c = flops / chips / HW["peak_flops"]
-    t_m = hbm / HW["hbm_bw"]
-    t_x = coll / HW["link_bw"]
+    hw = peaks(V5E)  # the production mesh is a v5e pod
+    t_c = flops / chips / hw["peak_flops"]
+    t_m = hbm / hw["hbm_bw"]
+    t_x = coll / hw["link_bw"]
     bound = max(t_c, t_m, t_x)
-    ideal = max(flops_useful / chips / HW["peak_flops"],
-                useful_bytes / HW["hbm_bw"])
+    ideal = max(flops_useful / chips / hw["peak_flops"],
+                useful_bytes / hw["hbm_bw"])
     dom = {"compute": t_c, "memory": t_m, "collective": t_x}
     dominant = max(dom, key=dom.get)
     hints = {

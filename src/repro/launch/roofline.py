@@ -1,9 +1,9 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-Terms (TPU v5e targets):
-  compute    = FLOPs / peak_FLOPs            (197 TFLOP/s bf16 per chip)
-  memory     = bytes accessed / HBM_bw       (819 GB/s per chip)
-  collective = collective bytes / link_bw    (~50 GB/s per ICI link)
+Terms, per chip of the device kind the program targets (``peaks``):
+  compute    = FLOPs / peak_FLOPs
+  memory     = bytes accessed / HBM_bw
+  collective = collective bytes / link_bw
 
 ``cost_analysis`` describes the per-device SPMD program, so terms are
 per-chip seconds directly. Collective bytes are parsed from the optimized
@@ -16,11 +16,31 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass
 
-HW = {
-    "peak_flops": 197e12,   # bf16 per chip
-    "hbm_bw": 819e9,        # bytes/s per chip
-    "link_bw": 50e9,        # bytes/s per ICI link
+# ``jax.Device.device_kind`` of a TPU v5e chip — the dry runs' compile target
+V5E = "TPU v5 lite"
+
+# Published per-chip peaks, keyed by ``device_kind``. Source: Google Cloud
+# documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s chip-to-chip interconnect over 4 ICI links (50 GB/s each).
+PEAKS = {
+    V5E: {
+        "peak_flops": 197e12,   # bf16 per chip
+        "hbm_bw": 819e9,        # bytes/s per chip
+        "link_bw": 50e9,        # bytes/s per ICI link
+        "hbm_bytes": 16e9,
+    },
 }
+
+
+def peaks(device_kind: str) -> dict:
+    """Peak rates of one chip of ``device_kind``; a kind without a
+    published entry is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peak rates for device kind "
+                         f"{device_kind!r} (known: {sorted(PEAKS)})") from None
+
 
 VMEM_BYTES = 16 * 2 ** 20   # per-core VMEM — the old single-dispatch cap
 
@@ -68,18 +88,19 @@ class Roofline:
     coll_bytes: float
     model_flops: float          # 6·N·D (train) or 2·N·D (decode), per chip
     chips: int
+    hw = peaks(V5E)             # the dry runs compile for a v5e chip
 
     @property
     def t_compute(self) -> float:
-        return self.flops / HW["peak_flops"]
+        return self.flops / self.hw["peak_flops"]
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / HW["hbm_bw"]
+        return self.hbm_bytes / self.hw["hbm_bw"]
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes / HW["link_bw"]
+        return self.coll_bytes / self.hw["link_bw"]
 
     @property
     def dominant(self) -> str:
@@ -95,7 +116,7 @@ class Roofline:
     def roofline_fraction(self) -> float:
         """useful compute time / total bound time (the perf score)."""
         bound = max(self.t_compute, self.t_memory, self.t_collective)
-        return (self.model_flops / HW["peak_flops"]) / max(bound, 1e-12)
+        return (self.model_flops / self.hw["peak_flops"]) / max(bound, 1e-12)
 
     def as_dict(self) -> dict:
         return {
@@ -171,7 +192,9 @@ def streaming_vs_twopass(ns=(2048, 8192, 32768, 65536), B: int = 128,
     Off-TPU the kernels run in interpret mode — a Python-stepped grid whose
     wall-clock says nothing about HBM traffic — so measurement is capped at
     ``measure_n_cap`` rows there and the modeled bytes carry the
-    comparison; on TPU the cap is lifted and the timings are real."""
+    comparison; on TPU the cap is lifted and the timings are real. The
+    modeled seconds use the v5e's published HBM bandwidth."""
+    hbm_bw = peaks(V5E)["hbm_bw"]
     rows = []
     for n in ns:
         m = modeled_scan_bytes(B, n, d, k, masked=masked)
@@ -179,8 +202,8 @@ def streaming_vs_twopass(ns=(2048, 8192, 32768, 65536), B: int = 128,
             "n": int(n),
             **m,
             "hbm_ratio": m["twopass_bytes"] / m["streaming_bytes"],
-            "t_memory_twopass_s": m["twopass_bytes"] / HW["hbm_bw"],
-            "t_memory_streaming_s": m["streaming_bytes"] / HW["hbm_bw"],
+            "t_memory_twopass_s": m["twopass_bytes"] / hbm_bw,
+            "t_memory_streaming_s": m["streaming_bytes"] / hbm_bw,
             "exceeds_vmem": m["score_block_bytes"] > VMEM_BYTES,
         }
         if measure:

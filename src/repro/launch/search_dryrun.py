@@ -21,7 +21,6 @@ import json
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.launch.mesh import make_production_mesh
@@ -41,8 +40,10 @@ def make_naive_search_step(mesh, k: int, axis: str = "data"):
             vals, ids = jax.lax.top_k(flat, k)
             return vals, ids
 
-        return shard_map(shard_fn, mesh=mesh, in_specs=(P(axis, None), P()),
-                         out_specs=(P(), P()), check_rep=False)(db, qvecs)
+        return jax.shard_map(shard_fn, mesh=mesh,
+                             in_specs=(P(axis, None), P()),
+                             out_specs=(P(), P()),
+                             check_vma=False)(db, qvecs)
     return step
 
 

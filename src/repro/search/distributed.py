@@ -1,28 +1,27 @@
 """Distributed vector-search serving (DESIGN.md §3, §5).
 
 The database rows are sharded across the data-parallel axis; every device
-scans its shard with the fused distance+top-k path (the Pallas kernels on
-TPU; their jnp oracle elsewhere) and only the per-shard top-k (k values +
-global ids) crosses the network — a tournament merge, never raw rows.
+scans its shard with one XLA matmul + ``lax.top_k`` (f32 at ``EXACT``
+precision, masked by ``valid_n`` and an optional sharded ``bad`` row
+bitmap) and only the per-shard top-k (k values + global ids) crosses the
+network — a tournament merge, never raw rows.
 
 ``search_step`` is jit/lower-able with ShapeDtypeStructs, so the same
-multi-pod dry-run methodology applies to the serving plane (reported as an
-extra, beyond-the-40-cells row in EXPERIMENTS.md §Dry-run).
+multi-pod dry-run methodology applies to the serving plane
+(``launch/search_dryrun.py``).
 """
 from __future__ import annotations
 
-
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-
+from repro.kernels.common import EXACT
 from repro.kernels.topk.kernel import NEG_INF
 
 
 def _local_scan(db_shard, qvecs, k, shard_offset, valid_n=None, bad=None):
-    scores = qvecs @ db_shard.T                       # (Q, N_local)
+    scores = jnp.matmul(qvecs, db_shard.T, precision=EXACT)  # (Q, N_local)
     masked = None
     if valid_n is not None:
         # rows at global index >= valid_n are column-store padding
@@ -82,11 +81,14 @@ def make_search_step(mesh: Mesh, k: int, axis: str = "data",
         args = (db, qvecs) + ((bad,) if masked else ())
         # outputs are bitwise-identical on every shard after the gather +
         # top_k, but replication-rule inference can't see that — disable the check
-        return shard_map(shard_fn, mesh=mesh,
-                         in_specs=in_specs,
-                         out_specs=(P(), P()), check_rep=False)(*args)
+        return jax.shard_map(shard_fn, mesh=mesh,
+                             in_specs=in_specs,
+                             out_specs=(P(), P()),
+                             check_vma=False)(*args)
 
-    return step
+    # one compiled program per step: called eagerly, shard_map would
+    # dispatch (and compile) its body op by op
+    return jax.jit(step)
 
 
 def distributed_rerank(mesh: Mesh, db, cand_ids, qvec, k: int,
@@ -101,13 +103,13 @@ def distributed_rerank(mesh: Mesh, db, cand_ids, qvec, k: int,
         local = ids - rank * n_local
         mine = (local >= 0) & (local < n_local)
         rows = db_local[jnp.clip(local, 0, n_local - 1)]
-        scores = rows @ q
+        scores = jnp.matmul(rows, q, precision=EXACT)
         scores = jnp.where(mine, scores, 0.0)
         scores = jax.lax.psum(scores, axis)  # exactly one shard owns each id
         return scores
 
-    scores = shard_map(shard_fn, mesh=mesh,
-                       in_specs=(P(axis, None), P(), P()),
-                       out_specs=P(), check_rep=False)(db, cand_ids, qvec)
+    scores = jax.shard_map(shard_fn, mesh=mesh,
+                           in_specs=(P(axis, None), P(), P()),
+                           out_specs=P(), check_vma=False)(db, cand_ids, qvec)
     vals, pos = jax.lax.top_k(scores, k)
     return vals, cand_ids[pos]

@@ -111,9 +111,11 @@ class ColumnStore:
             nd_pad = _round_up(d, self.block_dim) - d
             if np_pad or nd_pad:
                 mat = np.pad(mat, ((0, np_pad), (0, nd_pad)))
-            arr = jnp.asarray(mat)
-            if self.mesh is not None:
-                arr = jax.device_put(arr, row_sharding(self.mesh, self.axis))
+            # the host array goes straight to its placement: each device
+            # receives only its row shard under a mesh
+            sharding = (None if self.mesh is None
+                        else row_sharding(self.mesh, self.axis))
+            arr = jax.device_put(np.asarray(mat, dtype=np.float32), sharding)
             self._device[vid] = DeviceColumn(vid=vid, data=arr, n_rows=n, dim=d)
         return self._device[vid]
 

@@ -73,6 +73,7 @@ predicate-uniform (``GroupKey.pred``), so the keep bitmap is one shared
 """
 from __future__ import annotations
 
+import functools
 import os
 import time
 from dataclasses import dataclass, field
@@ -84,13 +85,14 @@ import numpy as np
 from repro.core.types import Query, QueryPlan, Workload
 from repro.data.vectors import MultiVectorDatabase
 from repro.index.base import exact_topk
+from repro.kernels.common import EXACT, default_interpret
 from repro.kernels.distance.kernel import batched_scores
 from repro.kernels.distance.ops import fused_scan
 from repro.kernels.streaming.ops import streaming_fused_scan
 from repro.kernels.topk.kernel import NEG_INF
 from repro.launch.roofline import modeled_scan_bytes
 from repro.obs import NULL_OBSERVER
-from repro.serve.columnstore import ColumnStore, DeviceColumn
+from repro.serve.columnstore import ColumnStore, DeviceColumn, row_sharding
 from repro.serve.compiler import PlanGroup, compile_batch
 
 # scores below this are masked tombstones / padding — never real candidates
@@ -173,10 +175,28 @@ class _FilterState:
         return self._dev[key]
 
 
-@jax.jit
-def _gather_scores(data: jnp.ndarray, rows: jnp.ndarray, qmat: jnp.ndarray):
-    """Per-query gathered-row scoring: (N,d), (B,R) int32, (B,d) -> (B,R)."""
-    return jnp.einsum("brd,bd->br", data[rows], qmat)
+# device bytes one IVF gather step may materialise: (B, chunk, d) f32 rows
+_GATHER_BYTES = 256 * 2 ** 20
+
+
+def gather_chunk(B: int, R: int, d: int) -> int:
+    """Rows per query that ``_gather_scores`` gathers per step: all R when
+    (B, R, d) fits ``_GATHER_BYTES``, else the largest count that does —
+    at the paper's 1M rows a probe union can cover most of the table, and
+    one (B, R, d) gather would not fit the chip's HBM."""
+    return max(1, min(R, _GATHER_BYTES // (B * d * 4)))
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def _gather_scores(data: jnp.ndarray, rows: jnp.ndarray, qmat: jnp.ndarray,
+                   chunk: int):
+    """Per-query gathered-row scoring: (N,d), (B,R) int32, (B,d) -> (B,R),
+    ``chunk`` rows per query at a time (R a multiple of ``chunk``)."""
+    B, R = rows.shape
+    steps = rows.reshape(B, R // chunk, chunk).transpose(1, 0, 2)
+    out = jax.lax.map(lambda r: jnp.einsum("brd,bd->br", data[r], qmat,
+                                           precision=EXACT), steps)
+    return out.transpose(1, 0, 2).reshape(B, R)
 
 
 @jax.jit
@@ -206,7 +226,6 @@ def cache_probe_scan(qmat, mat, valid_n, interpret: bool | None = None):
     second table), a jitted XLA mirror under interpret mode (Pallas
     interpret runs its grid in Python). Returns host (vals, ids) with
     vals = -(squared L2); rows at or past ``valid_n`` are masked."""
-    from repro.kernels.common import default_interpret
     if interpret is None:
         interpret = default_interpret()
     qmat = jnp.asarray(qmat, dtype=jnp.float32)
@@ -916,7 +935,6 @@ class BatchEngine:
         goes through one jitted XLA matmul instead — interpret-mode kernels
         execute their grid in Python, which would serialize the batch and
         invert the benchmark."""
-        from repro.kernels.common import default_interpret
         interp = self.interpret if self.interpret is not None else default_interpret()
         if interp:
             return _xla_scores(qmat, sub)
@@ -948,13 +966,14 @@ class BatchEngine:
             bad = None
             if dead_mask is not None or keep_mask is not None:
                 # compose tombstones ∪ ¬predicate into one (N,) f32 row
-                # bitmap, sharded P(axis) exactly like the column rows
-                bad = jnp.zeros(int(col.data.shape[0]), dtype=jnp.float32)
+                # bitmap on the host and place it sharded P(axis) exactly
+                # like the column rows — never whole on one device first
+                bad = np.zeros(int(col.data.shape[0]), dtype=np.float32)
                 if dead_mask is not None:
-                    bad = jnp.maximum(bad, dead_mask.astype(jnp.float32))
+                    bad[np.asarray(dead_mask, dtype=bool)] = 1.0
                 if keep_mask is not None:
-                    bad = jnp.maximum(
-                        bad, 1.0 - keep_mask.astype(jnp.float32))
+                    bad[~np.asarray(keep_mask, dtype=bool)] = 1.0
+                bad = jax.device_put(bad, row_sharding(self.mesh, self.axis))
             key = (k, col.n_rows, bad is not None)
             if key not in self._dist_steps:
                 from repro.search.distributed import make_search_step
@@ -1109,10 +1128,13 @@ class BatchEngine:
             ndists[i] += idx.n_lists + int(rows.shape[0])
 
         R = max(max((r.shape[0] for r in rows_list), default=1), 1)
-        rows_mat = np.zeros((len(items), R), dtype=np.int32)
+        chunk = gather_chunk(len(items), R, col.padded_dim)
+        rows_mat = np.zeros((len(items), -(-R // chunk) * chunk),
+                            dtype=np.int32)
         for i, rows in enumerate(rows_list):
             rows_mat[i, : rows.shape[0]] = rows
-        scores = np.asarray(_gather_scores(col.data, jnp.asarray(rows_mat), qmat))
+        scores = np.asarray(_gather_scores(col.data, jnp.asarray(rows_mat),
+                                           qmat, chunk=chunk))
         for i, (it, rows) in enumerate(zip(items, rows_list)):
             if rows.shape[0] == 0:
                 if scored is not None:

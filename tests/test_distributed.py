@@ -1,6 +1,7 @@
 """Multi-device tests (spawned subprocess with host-platform device count —
 the main test process must keep a single device)."""
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -92,7 +93,8 @@ def test_multidevice_subprocess():
     proc = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
                           text=True, timeout=900,
                           env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                               "HOME": "/root"})
+                               "HOME": os.environ.get("HOME", "/tmp"),
+                               "JAX_PLATFORMS": "cpu"})
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT")][0]
     out = json.loads(line[len("RESULT"):])
