@@ -88,7 +88,7 @@ class BuildCoordinator:
                 future=self.executor.submit(build_fn,
                                             label=label or f"build:{key}"),
                 finalize=finalize,
-                t_submit=time.time() if now is None else now)
+                t_submit=time.perf_counter() if now is None else now)
             self._inflight[key] = build
         return build
 
@@ -108,7 +108,7 @@ class BuildCoordinator:
                 build.error = exc
                 self.failures.append(BuildFailure(
                     key=build.key, label=build.label, error=exc,
-                    t=time.time() if now is None else now))
+                    t=time.perf_counter() if now is None else now))
                 continue
             build.event = build.finalize(build.future.result(), now)
             build.finalized = True

@@ -56,9 +56,10 @@ from repro.tenancy.runtime import MultiTenantRuntime, Tenant
 SCENARIOS = ("steady", "churn", "tenant_skew")
 
 # wall-clock metric series: host measurements, excluded from the
-# deterministic snapshot (DESIGN.md §15 determinism contract)
+# deterministic snapshot (DESIGN.md §15 determinism contract); so are
+# compiles, which depend on what the process compiled before
 WALL_SERIES = frozenset({"executor_task_ms", "dispatch_ms",
-                         "ticket_wall_ms", "flush_wait_ms"})
+                         "ticket_wall_ms", "flush_wait_ms", "compiles"})
 
 
 @dataclass(frozen=True)
@@ -263,19 +264,20 @@ def _drive(rt, events, executor) -> tuple[list, float]:
     tickets = []
     peak = _device_bytes(rt)
     multi = isinstance(rt, MultiTenantRuntime)
-    for ev in events:
-        if isinstance(ev, TimedQuery):
-            if multi:
-                tickets.append(rt.submit(ev.tenant, ev.query, ev.t))
+    with rt.batcher.virtual_time():
+        for ev in events:
+            if isinstance(ev, TimedQuery):
+                if multi:
+                    tickets.append(rt.submit(ev.tenant, ev.query, ev.t))
+                else:
+                    tickets.append(rt.submit(ev.query, ev.t))
             else:
-                tickets.append(rt.submit(ev.query, ev.t))
-        else:
-            rt.apply_timed(ev)
-        rt.tick(ev.t)
-        executor.run_all()
-        peak = max(peak, _device_bytes(rt))
-    last = events[-1].t if events else 0.0
-    rt.drain(last)
+                rt.apply_timed(ev)
+            rt.tick(ev.t)
+            executor.run_all()
+            peak = max(peak, _device_bytes(rt))
+        last = events[-1].t if events else 0.0
+        rt.drain(last)
     executor.run_all()
     if isinstance(rt, IngestRuntime):
         rt.wait_maintenance(now=last)

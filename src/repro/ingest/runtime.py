@@ -184,7 +184,7 @@ class IngestRuntime(OnlineRuntime):
     # ---- serving loop -----------------------------------------------------
 
     def tick(self, now: float | None = None):
-        now = time.time() if now is None else now
+        now = time.perf_counter() if now is None else now
         done = super().tick(now)
         if self.ingest.auto_maintain:
             self.maintain(now)
@@ -194,14 +194,15 @@ class IngestRuntime(OnlineRuntime):
         """Replay a churn trace (TimedQuery | TimedMutation, by arrival
         time). Returns one completed ticket per QUERY in arrival order."""
         tickets = []
-        for ev in events:
-            if isinstance(ev, TimedQuery):
-                tickets.append(self.submit(ev.query, ev.t))
-            else:
-                self.apply_timed(ev)
-            self.tick(ev.t)
-        last = events[-1].t if events else 0.0
-        self.drain(last)
+        with self.batcher.virtual_time():
+            for ev in events:
+                if isinstance(ev, TimedQuery):
+                    tickets.append(self.submit(ev.query, ev.t))
+                else:
+                    self.apply_timed(ev)
+                self.tick(ev.t)
+            last = events[-1].t if events else 0.0
+            self.drain(last)
         self.retuner.join()
         self.wait_maintenance(now=last)  # finalize an in-flight async build
         return tickets
@@ -215,7 +216,7 @@ class IngestRuntime(OnlineRuntime):
         (it compacts as part of its swap — compacting separately would be
         wasted work), else policy-triggered compaction (async when
         configured: cut now, build off-path, finalize at a later tick)."""
-        now = time.time() if now is None else now
+        now = time.perf_counter() if now is None else now
         if self.builds is not None:
             if self.builds.poll(now):
                 return
@@ -255,7 +256,7 @@ class IngestRuntime(OnlineRuntime):
         fold (the stop-the-world baseline ``compact_async`` is measured
         against; nothing lands between cut and rebase, so replay is
         empty)."""
-        now = time.time() if now is None else now
+        now = time.perf_counter() if now is None else now
         t0 = time.time()
         with self.batcher.lock:
             self.observer.event("compaction_cut", reason=reason, mode="sync")
@@ -286,7 +287,7 @@ class IngestRuntime(OnlineRuntime):
         flush observes exactly one consistent (store, generation, table)
         triple throughout. Returns the ``BackgroundBuild`` handle, or None
         when a build is already in flight."""
-        now = time.time() if now is None else now
+        now = time.perf_counter() if now is None else now
         builds = self._build_coordinator()
         with self.batcher.lock:  # pin configuration vs a concurrent swap
             cut = self.compactor.cut()
@@ -378,7 +379,7 @@ class IngestRuntime(OnlineRuntime):
                     now: float | None = None) -> DataRetuneEvent:
         """Data drift: compact, retrain estimators on the live table, and
         retune — the data-side analogue of the query-drift lifecycle."""
-        now = time.time() if now is None else now
+        now = time.perf_counter() if now is None else now
         self._last_data_fire = now
         self.observer.event("data_drift", reason=report.reason or "",
                             churn=report.churn_fraction,

@@ -23,29 +23,22 @@ Usage::
     # rep["stages"]["dispatch"]["p99"], rep["timeline"], rep["metrics"], ...
 
 Stage rows aggregate the DIRECT children of each ticket's root span
-(enqueue / semcache_probe / flush_wait / dispatch / merge — disjoint by
-construction, so they sum to ≈ end-to-end); ``coverage`` reports that
-sum over the measured total per ticket. Dispatch spans carry the
-kernel-level attribution (plan signature, index kinds, batch size,
-modeled HBM bytes from ``launch/roofline.py``) on their ``plan_group``
-children.
+(admission / enqueue / semcache_probe / flush_wait / dispatch / merge —
+disjoint by construction, so they sum to ≈ end-to-end); ``coverage``
+reports that sum over the measured total per ticket. Dispatch spans
+carry the kernel-level attribution (plan signature, index kinds, batch
+size, compiles) on their ``plan_group`` children, whose ``fetch``
+children are the blocking reads of scan results.
 """
 from __future__ import annotations
 
 from repro.obs import Histogram, Timeline, Trace
 
-_ATTR_KEYS = ("hit", "batch", "union", "index_kinds", "hbm_bytes_modeled")
+_ATTR_KEYS = ("hit", "batch", "union", "index_kinds", "compiles")
 
 
 def _fmt_attrs(attrs: dict) -> str:
-    parts = []
-    for key in _ATTR_KEYS:
-        if key in attrs:
-            val = attrs[key]
-            if key == "hbm_bytes_modeled":
-                parts.append(f"hbm={val / 1e6:.2f}MB")
-            else:
-                parts.append(f"{key}={val}")
+    parts = [f"{key}={attrs[key]}" for key in _ATTR_KEYS if key in attrs]
     return (" [" + " ".join(parts) + "]") if parts else ""
 
 
@@ -90,21 +83,6 @@ def stage_breakdown(traces) -> dict:
                               if coverages else 0.0)}
 
 
-def hbm_attribution(traces) -> dict:
-    """Modeled HBM bytes per (index kinds) signature, summed over every
-    plan_group span — the bandwidth-cost side of the latency breakdown."""
-    out: dict = {}
-    for trace in traces:
-        for span in trace.root.walk():
-            if span.name != "plan_group":
-                continue
-            key = ",".join(span.attrs.get("index_kinds", ()))
-            row = out.setdefault(key, {"groups": 0, "hbm_bytes_modeled": 0.0})
-            row["groups"] += 1
-            row["hbm_bytes_modeled"] += span.attrs.get("hbm_bytes_modeled", 0.0)
-    return out
-
-
 def timeline_table(timeline: Timeline, t0: float | None = None,
                    t1: float | None = None) -> list[dict]:
     return [ev.as_dict() for ev in timeline.window(t0, t1)]
@@ -124,12 +102,11 @@ def render_timeline(timeline: Timeline, t0: float | None = None,
 
 
 def report(observer) -> dict:
-    """JSON-able report: stage breakdown + HBM attribution + timeline +
-    metrics-registry snapshot."""
+    """JSON-able report: stage breakdown + timeline + metrics-registry
+    snapshot."""
     traces = list(observer.traces)
     return {"n_traces": len(traces),
             "breakdown": stage_breakdown(traces),
-            "hbm": hbm_attribution(traces),
             "timeline": ([] if observer.timeline is None
                          else [ev.as_dict() for ev in observer.timeline.window()]),
             "timeline_kinds": ({} if observer.timeline is None
@@ -150,11 +127,6 @@ def render_report(observer) -> str:
                           if isinstance(v, float) else f"{k}={v}"
                           for k, v in row.items())
         lines.append(f"  {name:<16} {cells}")
-    if rep["hbm"]:
-        lines.append("== modeled HBM bytes by index kinds ==")
-        for key, row in sorted(rep["hbm"].items()):
-            lines.append(f"  {key or 'flat':<16} groups={row['groups']}  "
-                         f"hbm={row['hbm_bytes_modeled'] / 1e6:.2f}MB")
     lines.append("== runtime timeline ==")
     lines.append(render_timeline(observer.timeline)
                  if observer.timeline is not None else "(no timeline)")

@@ -8,13 +8,26 @@ call site and changes no behavior — observability is strictly
 read-only, so disabled runs are bit-identical to uninstrumented code.
 
 Span parenting is explicit-or-implicit: ``span(...)`` opens a context
-manager that pushes onto a ``threading.local`` stack, so nested calls
+manager that pushes onto a per-thread stack of live spans, so nested calls
 on the same thread (engine plan-group under scheduler dispatch) parent
 automatically; ``span_at(...)`` builds an already-closed span from two
 timestamps and attaches it to an explicit parent. Cross-thread
 parenting never consults the stack — a flush job's tickets carry their
 traces, and the worker adopts the shared dispatch span into each
 ticket's root (see scheduler).
+
+Live spans (``span(...)``, not ``span_at``) also open a
+``jax.profiler.TraceAnnotation`` named ``mint.<span name>`` while they
+are open, so the program's stages appear in a profiler capture on the
+device trace's clock. Spans and the runtime share one clock,
+``time.perf_counter``.
+
+Compiles are charged to the span that caused them: the first enabled
+observer registers ONE process-wide ``jax.monitoring`` duration listener
+for XLA's backend-compile event, which adds to the ``compiles``
+attribute of the innermost live span open on the compiling thread and
+bumps ``compiles{span=<name>}`` in that span's observer. A compile with
+no live span open is charged nowhere.
 
 Completed ticket traces land in a bounded ``deque`` (``obs.traces``)
 for reports and tests.
@@ -24,6 +37,9 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
+
+import jax
+from jax.profiler import TraceAnnotation
 
 from .metrics import MetricsRegistry
 from .timeline import Timeline
@@ -98,24 +114,61 @@ class NullObserver:
 
 NULL_OBSERVER = NullObserver()
 
+# the event the benchmark's compile count listens for too
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_listen_lock = threading.Lock()  # guards the one-time registration
+_listening = False
+_live = threading.local()  # .ctxs: this thread's open live spans, any observer
+
+
+def _live_ctxs() -> list:
+    ctxs = getattr(_live, "ctxs", None)
+    if ctxs is None:
+        ctxs = _live.ctxs = []
+    return ctxs
+
+
+def _on_duration(event: str, duration: float, **_) -> None:
+    """Charge an XLA compile to the innermost live span of this thread."""
+    if event != COMPILE_EVENT:
+        return
+    ctxs = _live_ctxs()
+    if not ctxs:
+        return
+    ctx = ctxs[-1]
+    ctx.span.attrs["compiles"] = ctx.span.attrs.get("compiles", 0) + 1
+    ctx._obs.counter("compiles", span=ctx.span.name)
+
+
+def _listen_for_compiles() -> None:
+    global _listening
+    with _listen_lock:
+        if not _listening:
+            jax.monitoring.register_event_duration_secs_listener(_on_duration)
+            _listening = True
+
 
 class _SpanCtx:
-    """Context manager that pushes/pops the thread-local span stack."""
+    """Context manager that pushes/pops the thread's live-span stack and
+    holds the span's profiler annotation open."""
 
-    __slots__ = ("_obs", "span")
+    __slots__ = ("_obs", "span", "_ann")
 
     def __init__(self, obs: "Observer", span: Span):
         self._obs = obs
         self.span = span
 
     def __enter__(self) -> Span:
-        self._obs._stack().append(self.span)
+        _live_ctxs().append(self)
+        self._ann = TraceAnnotation(f"mint.{self.span.name}")
+        self._ann.__enter__()
         return self.span
 
     def __exit__(self, *exc) -> bool:
-        stack = self._obs._stack()
-        if stack and stack[-1] is self.span:
-            stack.pop()
+        self._ann.__exit__(None, None, None)
+        ctxs = _live_ctxs()
+        if ctxs and ctxs[-1] is self:
+            ctxs.pop()
         self.span.end()
         return False
 
@@ -132,14 +185,7 @@ class Observer:
             MetricsRegistry(max_series_per_name=max_series_per_name)
         self.timeline = Timeline(capacity=timeline_capacity)
         self.traces: deque = deque(maxlen=int(max_traces))
-        self._tls = threading.local()
-
-    def _stack(self) -> list:
-        stack = getattr(self._tls, "stack", None)
-        if stack is None:
-            stack = []
-            self._tls.stack = stack
-        return stack
+        _listen_for_compiles()
 
     # ---- traces ----------------------------------------------------------
 
@@ -156,15 +202,16 @@ class Observer:
 
     def span(self, name: str, parent: Span | None = None,
              t0: float | None = None, **attrs) -> _SpanCtx:
-        """Open a span as a context manager.
+        """Open a live span as a context manager; it is annotated in the
+        profiler's trace as ``mint.<name>`` and takes the compiles made
+        while it is the innermost live span of its thread.
 
         Parents to ``parent`` if given, else to the current span on this
         thread, else floats (attach it yourself via ``Span.add``).
         """
         sp = Span(name, t0=t0, attrs=attrs)
         if parent is None:
-            stack = self._stack()
-            parent = stack[-1] if stack else None
+            parent = self.current()
         if parent is not None:
             parent.add(sp)
         return _SpanCtx(self, sp)
@@ -179,8 +226,11 @@ class Observer:
         return sp
 
     def current(self) -> Span | None:
-        stack = self._stack()
-        return stack[-1] if stack else None
+        """This observer's innermost live span on this thread."""
+        for ctx in reversed(_live_ctxs()):
+            if ctx._obs is self:
+                return ctx.span
+        return None
 
     # ---- timeline + metrics ---------------------------------------------
 

@@ -1,7 +1,9 @@
 """Per-ticket span trees.
 
-A ``Trace`` owns a root ``Span`` covering submit -> done; stages hang
-off the root as children. Spans are plain objects (no registry, no
+A ``Trace`` owns a root ``Span`` covering arrival -> done (the arrival
+is the caller's ``now`` where that is a moment on the runtime clock,
+else the submit); stages hang off the root as children, the first of
+them ``admission`` (arrival -> submit) where the arrival came first. Spans are plain objects (no registry, no
 thread affinity) so a span built on a WorkerPool thread can be
 *adopted* by reference into several tickets' trees — one async flush
 serves a whole micro-batch, and each served ticket's tree includes the

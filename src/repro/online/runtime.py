@@ -162,14 +162,16 @@ class OnlineRuntime:
         return plan
 
     def submit(self, query: Query, now: float | None = None) -> Ticket:
-        now = time.time() if now is None else now
+        """Admit one query; ``now`` is its arrival on the runtime clock
+        (``time.perf_counter``), the call itself when omitted."""
+        now = time.perf_counter() if now is None else now
         self.monitor.observe(query)
         return self.batcher.submit(query, now)
 
     def tick(self, now: float | None = None) -> list[Ticket]:
         """Advance the serving loop: flush due micro-batches, then give the
         background re-tuner a chance to react to drift."""
-        now = time.time() if now is None else now
+        now = time.perf_counter() if now is None else now
         done = self.batcher.poll(now)
         self.retuner.maybe_retune(now)
         return done
@@ -181,11 +183,12 @@ class OnlineRuntime:
         """Replay a timed trace in virtual time; returns one ticket per
         query in arrival order (all completed)."""
         tickets = [None] * len(trace)
-        for i, tq in enumerate(trace):
-            tickets[i] = self.submit(tq.query, tq.t)
-            self.tick(tq.t)
-        last = trace[-1].t if trace else 0.0
-        self.drain(last)
+        with self.batcher.virtual_time():
+            for i, tq in enumerate(trace):
+                tickets[i] = self.submit(tq.query, tq.t)
+                self.tick(tq.t)
+            last = trace[-1].t if trace else 0.0
+            self.drain(last)
         self.retuner.join()
         return tickets  # type: ignore[return-value]
 

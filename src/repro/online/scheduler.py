@@ -35,12 +35,18 @@ executor (``sync`` mode) behavior is bit-identical to the pre-async
 batcher: flushes execute inline on the submitting thread.
 
 Time is explicit (``now`` in seconds) so schedules are deterministic and
-simulation-driven; wall clock is used when ``now`` is omitted. Tickets
-additionally carry wall-clock submit/done stamps (``wall_wait_ms``) so
-latency benches stay meaningful under virtual-time traces.
+simulation-driven. The runtime's clock is ``time.perf_counter``, the one
+its spans use: it is taken when ``now`` is omitted, and a ``now`` on it
+that lies before the call is the request's arrival, where its trace
+opens (an ``admission`` stage covers arrival -> submit). Virtual-time
+replays mark themselves with ``virtual_time()``, so their trace time is
+never read as an arrival. Tickets additionally carry wall-clock
+submit/done stamps (``wall_wait_ms``) so latency benches stay meaningful
+under virtual-time traces.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import deque
@@ -206,6 +212,7 @@ class MicroBatcher:
         # dispatch/merge spans of a flush are adopted into every served
         # ticket's tree (async: built on the worker thread, parented back).
         self.obs = observer if observer is not None else NULL_OBSERVER
+        self._virtual = 0  # > 0 while a virtual-time replay runs
         self._inflight: list[_FlushJob] = []
         self.stats = BatcherStats()
         self._queues: dict[TenantId, deque[Ticket]] = {}
@@ -230,14 +237,30 @@ class MicroBatcher:
             return self._n_pending
         return len(self._queues.get(tenant, ()))
 
+    @contextlib.contextmanager
+    def virtual_time(self):
+        """Mark the calls inside as a replay in virtual time: their
+        ``now`` is trace time, never an arrival on the runtime clock."""
+        self._virtual += 1
+        try:
+            yield
+        finally:
+            self._virtual -= 1
+
+    def _arrival(self, now: float, t_sub: float) -> float:
+        """Where a ticket's trace opens: the caller's ``now`` when it is a
+        moment on the runtime clock no later than the submit, else the
+        submit itself (virtual time, or a ``now`` from another clock)."""
+        return now if not self._virtual and now <= t_sub else t_sub
+
     def submit(self, query: Query, now: float | None = None,
                tenant: TenantId = DEFAULT_TENANT,
                plan: QueryPlan | None = None) -> Ticket:
-        now = time.time() if now is None else now
+        obs = self.obs
+        t_sub = time.perf_counter() if obs.enabled or now is None else 0.0
+        now = t_sub if now is None else now
         t_wall = time.time()  # arrival stamp BEFORE the lock: a submitter
         # blocked behind a stop-the-world hold is measured as waiting
-        obs = self.obs
-        t_sub = time.perf_counter() if obs.enabled else 0.0
         with self.lock:
             t_plan1 = t_sub
             if plan is None:
@@ -247,8 +270,12 @@ class MicroBatcher:
             ticket = Ticket(query=query, plan=plan, t_submit=now,
                             tenant=tenant, t_submit_wall=t_wall)
             if obs.enabled:
+                t_arr = self._arrival(now, t_sub)
                 ticket.trace = obs.begin_trace(
-                    "ticket", t0=t_sub, qid=query.qid, tenant=str(tenant))
+                    "ticket", t0=t_arr, qid=query.qid, tenant=str(tenant))
+                if t_arr < t_sub:
+                    obs.span_at("admission", t_arr, t_sub,
+                                parent=ticket.trace.root)
                 obs.counter("tickets_submitted", tenant=str(tenant))
             if self.semcache is not None:
                 t_p0 = time.perf_counter() if obs.enabled else 0.0
@@ -305,7 +332,7 @@ class MicroBatcher:
         full batch is waiting. Returns the tickets completed by this call
         (async mode: whatever in-flight batches have landed since the last
         harvest — flushing and completing are decoupled there)."""
-        now = time.time() if now is None else now
+        now = time.perf_counter() if now is None else now
         with self.lock:
             flushed: list[Ticket] = []
             if self._n_pending:
@@ -326,7 +353,7 @@ class MicroBatcher:
         is no execution in flight, which is what the runtime's swap paths
         rely on (workers never take the batcher lock, so waiting while
         holding it cannot deadlock)."""
-        now = time.time() if now is None else now
+        now = time.perf_counter() if now is None else now
         out: list[Ticket] = []
         with self.lock:
             while self._n_pending:
@@ -453,8 +480,9 @@ class MicroBatcher:
         and ONE merge span, built on whichever thread executes and adopted
         by reference into every served ticket's tree — that is how async
         flush spans parent back to the tickets they serve. The dispatch
-        span is pushed as this thread's current span, so the engine's
-        plan-group spans (with modeled HBM bytes) nest under it."""
+        span is live (this thread's current span, annotated in the
+        profiler's trace), so the engine's plan-group spans nest under
+        it."""
         obs = self.obs
         if not obs.enabled:
             results = self.execute(tickets, staged) if pass_staged \

@@ -90,7 +90,6 @@ from repro.kernels.distance.kernel import batched_scores
 from repro.kernels.distance.ops import fused_scan
 from repro.kernels.streaming.ops import streaming_fused_scan
 from repro.kernels.topk.kernel import NEG_INF
-from repro.launch.roofline import modeled_scan_bytes
 from repro.obs import NULL_OBSERVER
 from repro.serve.columnstore import ColumnStore, DeviceColumn, row_sharding
 from repro.serve.compiler import PlanGroup, compile_batch
@@ -252,9 +251,10 @@ class BatchEngine:
                  axis: str = "data", interpret: bool | None = None,
                  streaming: bool | None = None, observer=None):
         self.db = db
-        # observability (DESIGN.md §14): plan-group spans with modeled HBM
-        # bytes nest under whatever span is current on the executing thread
-        # (the scheduler's dispatch span); NULL_OBSERVER keeps this free
+        # observability (DESIGN.md §14): plan-group spans, and the fetch
+        # spans of their blocking reads, nest under whatever span is current
+        # on the executing thread (the scheduler's dispatch span);
+        # NULL_OBSERVER keeps this free
         self.obs = observer if observer is not None else NULL_OBSERVER
         self.store = store
         self.mesh = mesh if mesh is not None else (cstore.mesh if cstore else None)
@@ -445,59 +445,40 @@ class BatchEngine:
     # ---- group execution --------------------------------------------------
 
     def _observed_group(self, group: PlanGroup, sq: dict | None = None):
-        """``_run_group`` wrapped in a ``plan_group`` span carrying the
-        kernel-level attribution: plan signature, index kinds, batch size,
-        and modeled HBM bytes (launch/roofline). The span parents to the
-        thread's current span — the scheduler's dispatch span when a flush
-        is executing — which accumulates the group bytes, so a ticket's
-        dispatch span totals the modeled bandwidth cost of its batch."""
+        """``_run_group`` wrapped in a live ``plan_group`` span carrying
+        the kernel-level attribution: plan signature, index kinds, batch
+        size. The span parents to the thread's current span — the
+        scheduler's dispatch span when a flush is executing."""
         if not self.obs.enabled:
             return self._run_group(group, sq=sq)
-        attrs = self._group_attrs(group)
-        with self.obs.span("plan_group", **attrs):
+        with self.obs.span("plan_group", **self._group_attrs(group)):
             out = self._run_group(group, sq=sq)
         self.obs.counter("plan_groups")
-        parent = self.obs.current()
-        if parent is not None:
-            parent.attrs["hbm_bytes_modeled"] = \
-                parent.attrs.get("hbm_bytes_modeled", 0.0) + \
-                attrs["hbm_bytes_modeled"]
         return out
 
     def _group_attrs(self, group: PlanGroup) -> dict:
-        """Host-metadata-only attribution (never touches device state):
-        the modeled bytes reuse ``modeled_scan_bytes`` with the group's
-        batch, the table's row count, and each scanned column's width —
-        streaming vs two-pass follows the engine's active scan path."""
-        B = len(group.items)
-        N = int(self.db.n_rows)
-        side = "streaming_bytes" if self.streaming else "twopass_bytes"
+        """Host-metadata-only attribution (never touches device state)."""
         kinds: list[str] = []
         plansig: list[tuple] = []
-        hbm = 0.0
         if not group.specs:  # flat plan: one scan of the concat column
             kinds.append("flat")
             plansig.append(("flat", group.key.vid, group.max_k))
-            d = int(self.db.dim(group.key.vid))
-            hbm += modeled_scan_bytes(B, N, d, min(group.max_k, N))[side]
         for spec, bucket in zip(group.specs, group.buckets):
             kind = spec.kind if self.store is not None else "flat"
             kinds.append(kind)
             plansig.append((kind, spec.vid, int(bucket)))
-            d = int(self.db.dim(spec.vid))
-            k_eff = min(int(bucket), N)
-            m = modeled_scan_bytes(B, N, d, k_eff)
-            if kind == "flat":
-                hbm += m[side]
-            elif kind == "ivf":
-                # centroid pass + gathered probe-union scan: the streaming
-                # model at probe depth is the closest single-number proxy
-                hbm += m["streaming_bytes"]
-            else:  # graph walks gather per-visit candidate blocks
-                hbm += float(B * k_eff * d * 4)
         return {"plan_sig": tuple(plansig), "index_kinds": tuple(kinds),
-                "access": group.key.access, "batch": B, "rows": N,
-                "hbm_bytes_modeled": float(hbm)}
+                "access": group.key.access, "batch": len(group.items),
+                "rows": int(self.db.n_rows)}
+
+    def _fetch(self, *arrays) -> list[np.ndarray]:
+        """Host copies of device results: the blocking read that waits
+        for the kernels producing them, in a live ``fetch`` span when
+        observed."""
+        if not self.obs.enabled:
+            return [np.asarray(a) for a in arrays]
+        with self.obs.span("fetch"):
+            return [np.asarray(a) for a in arrays]
 
     def _run_group(self, group: PlanGroup, sq: dict | None = None):
         if group.key.pred is not None:
@@ -992,7 +973,8 @@ class BatchEngine:
             vals, ids = fused_scan(qmat, col.data, k=k, valid_n=col.n_rows,
                                    dead_mask=dead_mask, keep_mask=keep_mask,
                                    interpret=self.interpret)
-        return np.asarray(vals), np.asarray(ids)
+        vals, ids = self._fetch(vals, ids)
+        return vals, ids
 
     # ---- mutation-aware scanning (repro.ingest) ---------------------------
 
@@ -1064,8 +1046,7 @@ class BatchEngine:
             delta=dcol.col.data, delta_valid_n=dcol.n_rows,
             delta_dead_mask=dcol.dead_mask, keep_mask=bkeep,
             delta_keep_mask=dkeep, interpret=self.interpret)
-        vals = np.asarray(vals)
-        ids = np.asarray(ids)
+        vals, ids = self._fetch(vals, ids)
         # combined-physical ids -> stable: delta rows are offset by the
         # PADDED base row count (the kernel's id space)
         base_pad_rows = int(col.data.shape[0])
@@ -1112,7 +1093,7 @@ class BatchEngine:
         cent = np.asarray(idx.centroids, dtype=np.float32)
         if col.padded_dim != cent.shape[1]:
             cent = np.pad(cent, ((0, 0), (0, col.padded_dim - cent.shape[1])))
-        csims = np.asarray(self._batched_scores(qmat, jnp.asarray(cent)))
+        csims, = self._fetch(self._batched_scores(qmat, jnp.asarray(cent)))
         self.counters.scan += 1
 
         rows_list = []
@@ -1133,8 +1114,8 @@ class BatchEngine:
                             dtype=np.int32)
         for i, rows in enumerate(rows_list):
             rows_mat[i, : rows.shape[0]] = rows
-        scores = np.asarray(_gather_scores(col.data, jnp.asarray(rows_mat),
-                                           qmat, chunk=chunk))
+        scores, = self._fetch(_gather_scores(col.data, jnp.asarray(rows_mat),
+                                             qmat, chunk=chunk))
         for i, (it, rows) in enumerate(zip(items, rows_list)):
             if rows.shape[0] == 0:
                 if scored is not None:
@@ -1191,7 +1172,7 @@ class BatchEngine:
                 np.stack([it.query.concat() for it in items]))
         if mv is None:
             sub = col.data[jnp.asarray(gunion.astype(np.int32))]
-            scores = np.asarray(self._batched_scores(qmat, sub))
+            scores, = self._fetch(self._batched_scores(qmat, sub))
         else:
             scores = self._mv_union_scores(mv, group, col, qmat, gunion)
         self.counters.rerank += 1

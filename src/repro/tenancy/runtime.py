@@ -255,7 +255,7 @@ class MultiTenantRuntime:
 
     def submit(self, tenant: TenantId, query: Query,
                now: float | None = None) -> Ticket:
-        now = time.time() if now is None else now
+        now = time.perf_counter() if now is None else now
         st = self._tenants[tenant]
         if st.retune_proxy is not None:
             st.retune_proxy.monitor.observe(query)
@@ -272,7 +272,7 @@ class MultiTenantRuntime:
         ones on drifted tenants. A tenant mid-retune never blocks another
         tenant's flushes: the tune+build runs on the pool, and this loop
         only pays the per-tenant drain+swap when a result is ready."""
-        now = time.time() if now is None else now
+        now = time.perf_counter() if now is None else now
         done = self.batcher.poll(now)
         for tid in self.tenants():
             st = self._tenants[tid]
@@ -288,14 +288,15 @@ class MultiTenantRuntime:
         allowed for ingest-enabled tenants); one completed ticket per
         QUERY, arrival order."""
         tickets = []
-        for tq in trace:
-            if isinstance(tq, TimedMutation):
-                self.apply_timed(tq)
-            else:
-                tickets.append(self.submit(tq.tenant, tq.query, tq.t))
-            self.tick(tq.t)
-        last = trace[-1].t if trace else 0.0
-        self.drain(last)
+        with self.batcher.virtual_time():
+            for tq in trace:
+                if isinstance(tq, TimedMutation):
+                    self.apply_timed(tq)
+                else:
+                    tickets.append(self.submit(tq.tenant, tq.query, tq.t))
+                self.tick(tq.t)
+            last = trace[-1].t if trace else 0.0
+            self.drain(last)
         self.join_drift_loops(now=last)
         return tickets
 

@@ -7,20 +7,28 @@ Three layers of guarantees:
     cardinality bounding, snapshot diff/merge round-trips, exporters;
   - per-ticket tracing: the sync and async serving paths both yield a
     COMPLETE stage set (enqueue / semcache_probe / flush_wait / dispatch
-    / merge) whose top-level stages are disjoint and sum to ≈ end-to-end
-    latency; async flush spans built on worker threads are adopted into
-    every served ticket's root; modeled HBM bytes ride on dispatch;
+    / merge, after an ``admission`` stage where the caller's ``now`` is
+    an arrival on the runtime clock) whose top-level stages are disjoint
+    and sum to ≈ end-to-end latency; async flush spans built on worker
+    threads are adopted into every served ticket's root; plan groups and
+    the fetches of their results nest under dispatch, in the span tree
+    and in a profiler capture; compiles are charged to the live span
+    that caused them;
   - zero-cost-when-disabled: observer-off runs produce bit-identical
     results through the NULL_OBSERVER seam, and seeded StepExecutor
     interleavings reproduce identical span trees and counters.
 """
 import json
 import threading
+import time
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.async_ import SerialExecutor, StepExecutor, WorkerPool
+from repro.autotune.replay import _drive
 from repro.core.tuner import Mint
 from repro.core.types import Constraints, Workload
 from repro.data.vectors import make_database, make_queries
@@ -29,6 +37,7 @@ from repro.obs import (COUNTER, GAUGE, HISTOGRAM, NULL_OBSERVER, Histogram,
                        MetricsRegistry, MetricsSnapshot, Observer, Timeline,
                        hist_quantile, hist_summary)
 from repro.online import OnlineRuntime, RuntimeConfig, hot_item_trace
+from repro.obs import observer as observer_mod
 from repro.online.semcache import SemanticCache
 
 K = 8
@@ -335,14 +344,15 @@ def test_sync_ticket_span_tree_is_complete_and_disjoint(db, mint, wl, cons,
         assert 0.9 <= tr.coverage() <= 1.1
         dsp = tr.find("dispatch")
         # kernel-level attribution rides on dispatch: plan groups nested
-        # via the thread-local stack, modeled HBM bytes accumulated up
+        # via the thread's live-span stack, each holding the fetch of its
+        # scan results
         groups = [s for s in dsp.walk() if s.name == "plan_group"]
         assert groups
         for g in groups:
-            assert g.attrs["hbm_bytes_modeled"] > 0
+            assert any(c.name == "fetch" for c in g.children)
             assert g.attrs["plan_sig"] and g.attrs["batch"] >= 1
-        assert dsp.attrs["hbm_bytes_modeled"] == pytest.approx(
-            sum(g.attrs["hbm_bytes_modeled"] for g in groups))
+        # virtual time: the trace opens at the submit, no admission stage
+        assert "admission" not in tr.stage_names()
         # plan_cache nests INSIDE enqueue (top-level stays disjoint)
         enq = tr.find("enqueue")
         assert all(c.name == "plan_cache" for c in enq.children)
@@ -387,7 +397,7 @@ def test_async_flush_spans_adopt_into_ticket_roots(db, mint, wl, cons,
         assert len(sizes) == 1 and sizes.pop() >= len(trs)
     for tr in full:
         assert 0.9 <= tr.coverage() <= 1.1
-        assert tr.find("dispatch").attrs["hbm_bytes_modeled"] > 0
+        assert tr.find("dispatch").find("fetch") is not None
     snap = rt.observer.metrics.snapshot()
     assert snap.get("executor_tasks", kind="flush")["value"] >= 1
     rt.close()
@@ -409,8 +419,10 @@ def test_seeded_interleavings_reproduce_span_trees_and_counters(
                    if tr.find("dispatch") else None)
                   for tr in rt.observer.traces]
         snap = rt.observer.metrics.snapshot()
+        # compiles depend on what this process compiled before, not on
+        # the interleaving
         counters = {k: v["value"] for k, v in snap.series.items()
-                    if v["kind"] == COUNTER}
+                    if v["kind"] == COUNTER and k[0] != "compiles"}
         hcounts = {k: v["data"]["count"] for k, v in snap.series.items()
                    if v["kind"] == HISTOGRAM}
         rt.close()
@@ -495,3 +507,181 @@ def test_snapshot_diff_clamps_counter_resets():
     # a clean diff carries no reset markers
     clean = s1.diff(s1)
     assert clean.resets == {} and "_resets" not in clean.as_dict()
+
+
+# ---- arrival, the profiler bridge, compiles by span ------------------------
+
+
+def _stages_disjoint(tr) -> bool:
+    stages = sorted(tr.stages(), key=lambda sp: sp.t0)
+    return all(a.t1 <= b.t0 for a, b in zip(stages, stages[1:]))
+
+
+@pytest.mark.parametrize("clock", ["runtime", "wall"])
+def test_admission_span_opens_the_trace_at_the_arrival(db, mint, wl, cons,
+                                                       tuned, clock):
+    """A ``now`` on the runtime clock is the arrival: the root opens
+    there and an ``admission`` stage covers arrival -> submit, disjoint
+    from the rest. A ``now`` from another clock (here the wall clock,
+    ahead of ``perf_counter``) is no arrival: the trace opens at the
+    submit."""
+    rt = _runtime(db, mint, wl, cons, tuned, max_batch=4, max_delay_ms=5.0,
+                  cooldown_s=1e9, drift_threshold=2.0, semcache=True,
+                  observe=True)
+    queries = [q for q, _ in wl][:3]
+    arrivals = []
+    for q in queries:
+        arrivals.append(time.perf_counter() - 0.05 if clock == "runtime"
+                        else time.time())
+        rt.submit(q, now=arrivals[-1])
+    rt.drain()
+    traces = list(rt.observer.traces)
+    assert len(traces) == len(queries)
+    for t_arr, tr in zip(arrivals, traces):
+        assert STAGES <= tr.stage_names()
+        assert _stages_disjoint(tr)
+        assert 0.9 <= tr.coverage() <= 1.1
+        adm = tr.find("admission")
+        if clock == "runtime":
+            assert tr.root.t0 == t_arr == adm.t0
+            assert adm.duration_ms >= 50.0
+            assert adm.t1 == tr.find("enqueue").t0
+        else:
+            assert adm is None and tr.root.t0 == tr.find("enqueue").t0
+    rt.close()
+
+
+@pytest.mark.parametrize("replay", ["run_trace", "_drive"])
+def test_virtual_time_replays_build_no_admission_span(db, mint, wl, cons,
+                                                      tuned, trace, replay):
+    """Trace time starts at 0, before any ``perf_counter`` reading: the
+    virtual-time replays mark themselves, so it is never read as an
+    arrival."""
+    rt = _runtime(db, mint, wl, cons, tuned, executor=StepExecutor(seed=0),
+                  max_batch=4, max_delay_ms=5.0, cooldown_s=1e9,
+                  drift_threshold=2.0, observe=True)
+    if replay == "run_trace":
+        rt.run_trace(trace)
+    else:
+        _drive(rt, trace, rt.executor)
+    traces = list(rt.observer.traces)
+    assert len(traces) == len(trace)
+    for tr in traces:
+        assert "admission" not in tr.stage_names()
+        assert tr.root.t0 == tr.find("enqueue").t0
+        assert 0.9 <= tr.coverage() <= 1.1
+    rt.close()
+
+
+def test_live_spans_nest_in_the_profiler_trace(db, mint, wl, cons, tuned,
+                                               tmp_path):
+    """A profiler capture of an observed flush holds, on the flushing
+    thread's host line, mint.dispatch ⊃ mint.plan_group ⊃ mint.fetch."""
+    rt = _runtime(db, mint, wl, cons, tuned, max_batch=4, max_delay_ms=5.0,
+                  cooldown_s=1e9, drift_threshold=2.0, observe=True)
+    queries = [q for q, _ in wl][:3]
+    for q in queries:  # warm: compile outside the capture
+        rt.submit(q)
+    rt.drain()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for q in queries:
+            rt.submit(q)
+        rt.drain()
+    finally:
+        jax.profiler.stop_trace()
+    rt.close()
+    from jax.profiler import ProfileData
+    pd = ProfileData.from_file(str(sorted(tmp_path.rglob("*.xplane.pb"))[-1]))
+    found = False
+    for plane in pd.planes:
+        for line in plane.lines:
+            evs = {}
+            for ev in line.events:
+                if ev.name.startswith("mint."):
+                    evs.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+            if "mint.dispatch" not in evs:
+                continue
+
+            def inside(inner, outer):
+                return any(o0 <= i0 and i1 <= o1
+                           for i0, i1 in evs[inner] for o0, o1 in evs[outer])
+
+            assert inside("mint.plan_group", "mint.dispatch")
+            assert inside("mint.fetch", "mint.plan_group")
+            found = True
+    assert found
+
+
+def test_compiles_are_charged_to_the_live_span_that_made_them(db, mint, wl,
+                                                              cons, tuned):
+    """A batch shape XLA has not compiled charges its compiles to the
+    plan group that asked for them, on the span and in the registry; a
+    shape already compiled charges nothing, nor does a compile with no
+    live span open."""
+    rt = _runtime(db, mint, wl, cons, tuned, max_batch=8, max_delay_ms=5.0,
+                  cooldown_s=1e9, drift_threshold=2.0, observe=True)
+    obs = rt.observer
+    queries = [q for q, _ in wl if q.vid == (0,)]
+
+    def flush(b):
+        for q in (queries * 8)[:b]:
+            rt.submit(q)
+        rt.drain()
+        return obs.traces[-1].find("plan_group")
+
+    def charged():
+        entry = obs.metrics.snapshot().get("compiles", span="plan_group")
+        return entry["value"] if entry else 0
+
+    jax.clear_caches()
+    first = flush(2)
+    n0 = charged()
+    assert first.attrs["compiles"] >= 1 and n0 >= first.attrs["compiles"]
+    again = flush(2)
+    assert "compiles" not in again.attrs and charged() == n0
+    new_shape = flush(5)
+    assert new_shape.attrs["compiles"] >= 1
+    assert charged() == n0 + new_shape.attrs["compiles"]
+    assert not any(k[0] == "compiles" and k[1] != (("span", "plan_group"),)
+                   for k in obs.metrics.snapshot().series)
+    jax.jit(lambda x: x * 3.0 + 1.0)(jnp.ones(7))  # no live span open
+    assert charged() == n0 + new_shape.attrs["compiles"]
+    rt.close()
+
+
+def test_disabled_observer_registers_no_listener_and_enters_no_annotation(
+        db, mint, wl, cons, tuned, trace, monkeypatch):
+    """The NULL seam adds no compile listener and no profiler annotation;
+    enabled observers register one listener per process, however many
+    there are, and annotate each live span as ``mint.<name>``."""
+    registered, entered = [], []
+
+    class Annotation:
+        def __init__(self, name):
+            entered.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(observer_mod, "_listening", False)
+    monkeypatch.setattr(jax.monitoring,
+                        "register_event_duration_secs_listener",
+                        registered.append)
+    monkeypatch.setattr(observer_mod, "TraceAnnotation", Annotation)
+    rt = _runtime(db, mint, wl, cons, tuned, max_batch=4, max_delay_ms=5.0,
+                  cooldown_s=1e9, drift_threshold=2.0, observe=False)
+    rt.run_trace(trace)
+    rt.close()
+    assert registered == [] and entered == []
+    obs = Observer()
+    Observer()
+    assert registered == [observer_mod._on_duration]
+    with obs.span("dispatch"):
+        with obs.span("plan_group"):
+            obs.span_at("rerank", 0.0, 1.0, parent=obs.current())
+    assert entered == ["mint.dispatch", "mint.plan_group"]
