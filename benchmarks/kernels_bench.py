@@ -37,7 +37,7 @@ def _parity_spot_check(seed: int = 0) -> dict:
     dead = jnp.asarray(rng.random(520) < 0.1)
     kw = dict(k=25, metric="cosine", valid_n=500, dead_mask=dead,
               delta=dlt, delta_valid_n=60)
-    vals, ids = streaming_fused_scan(q, db, **kw)
+    vals, ids, _ = streaming_fused_scan(q, db, **kw)
     rvals, rids = streaming_fused_scan_ref(q, db, **kw)
     ok = (np.array_equal(np.asarray(vals), np.asarray(rvals))
           and np.array_equal(np.asarray(ids), np.asarray(rids)))

@@ -45,7 +45,7 @@ def batch_exact_topk(data: np.ndarray, qvecs: np.ndarray, k: int,
         use_kernel = not default_interpret()
     if use_kernel:
         from repro.kernels.streaming.ops import streaming_fused_scan
-        vals, idx = streaming_fused_scan(
+        vals, idx, _ = streaming_fused_scan(
             jnp.asarray(qvecs), jnp.asarray(data),
             k=min(k, data.shape[0]))
         return np.asarray(idx, dtype=np.int64), np.asarray(vals)

@@ -39,7 +39,7 @@ def _scan_gathered(sub: np.ndarray, qvec: np.ndarray, ek: int,
         padded = np.zeros((max(128, 1 << (m - 1).bit_length()), sub.shape[1]),
                           dtype=np.float32)
         padded[:m] = sub
-        vals, idx = streaming_fused_scan(
+        vals, idx, _ = streaming_fused_scan(
             jnp.asarray(qvec[None, :]), jnp.asarray(padded), k=ek, valid_n=m)
         return np.asarray(idx[0], dtype=np.int64), np.asarray(vals[0])
     scores = sub @ qvec

@@ -31,6 +31,11 @@ two-pass oracle; equal-score ties follow ascending fold order exactly like
 the two-pass top-k kernel. Sentinel ties (masked rows) never enter the
 buffer in either path. The wrapper's final ``lax.top_k`` ordering pass is
 identical to the two-pass wrapper's.
+
+The fold runs only the rounds a tile can win: min(k, the most scores any
+real query row has above its buffer's k-th best). Rows whose buffer the
+tile cannot change cost their matmul, epilogue and that count, no rounds;
+the result is the same as k rounds, ties included.
 """
 from __future__ import annotations
 
@@ -44,16 +49,19 @@ from repro.kernels.topk.kernel import NEG_INF
 
 def streaming_kernel(*refs, n_base_tiles: int, n_k_blocks: int, bn: int,
                      k: int, metric: str, delta_id_offset: int,
-                     has_delta: bool):
+                     has_delta: bool, n_queries: int):
     """Kernel body. Operand order (delta refs only when ``has_delta``):
     q, base, [delta], qsq, basesq, [deltasq], base_bad, [delta_bad] ->
-    (vals, idxs) outputs + one (bm, bn) f32 accumulator scratch."""
+    (vals, idxs, rounds) outputs + one (bm, bn) f32 accumulator scratch.
+    ``rounds`` is an (8, 128) int32 block per query block, every element
+    the fold rounds the block has run so far."""
     if has_delta:
         (q_ref, db_ref, dlt_ref, qsq_ref, bsq_ref, dsq_ref,
-         bbad_ref, dbad_ref, vals_ref, idxs_ref, acc_ref) = refs
+         bbad_ref, dbad_ref, vals_ref, idxs_ref, rounds_ref, acc_ref) = refs
     else:
         (q_ref, db_ref, qsq_ref, bsq_ref, bbad_ref,
-         vals_ref, idxs_ref, acc_ref) = refs
+         vals_ref, idxs_ref, rounds_ref, acc_ref) = refs
+    i = pl.program_id(0)
     j = pl.program_id(1)
     kb = pl.program_id(2)
 
@@ -61,6 +69,7 @@ def streaming_kernel(*refs, n_base_tiles: int, n_k_blocks: int, bn: int,
     def _init_topk():
         vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
         idxs_ref[...] = jnp.zeros_like(idxs_ref)
+        rounds_ref[...] = jnp.zeros_like(rounds_ref)
 
     @pl.when(kb == 0)
     def _init_acc():
@@ -120,7 +129,19 @@ def streaming_kernel(*refs, n_base_tiles: int, n_k_blocks: int, bn: int,
             s = jnp.where(sel, NEG_INF, s)
             return s, vals, idxs
 
+        # Gate: a round improves a row only by consuming a score above the
+        # row's k-th best as the tile begins (maxima come out descending,
+        # vmin only rises), so min(k, that count) rounds do all k would.
+        # Padding rows past n_queries are left out: under l2 their scores
+        # vary and would hold the gate open for nothing.
+        vals = vals_ref[...]
+        thr = jnp.min(vals, axis=1, keepdims=True)            # (bm, 1)
+        row = i * bm + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        wins = (s > thr) & (row < n_queries)
+        cnt = jnp.sum(wins.astype(jnp.int32), axis=1)
+        trip = jnp.minimum(jnp.max(cnt), k)
+        rounds_ref[...] += trip
         _, vals, idxs = jax.lax.fori_loop(
-            0, k, fold, (s, vals_ref[...], idxs_ref[...]))
+            0, trip, fold, (s, vals, idxs_ref[...]))
         vals_ref[...] = vals
         idxs_ref[...] = idxs

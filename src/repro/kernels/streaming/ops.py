@@ -9,7 +9,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import default_interpret, pad_to
+from repro.kernels.common import cdiv, default_interpret, pad_to
 from repro.kernels.streaming.kernel import streaming_kernel
 
 
@@ -41,10 +41,12 @@ def streaming_fused_scan(q: jnp.ndarray, db: jnp.ndarray, k: int,
                          delta_keep_mask: jnp.ndarray | None = None,
                          bm: int = 128, bn: int = 128, bk: int = 128,
                          interpret: bool | None = None
-                         ) -> tuple[jnp.ndarray, jnp.ndarray]:
+                         ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """(B, d) queries over (N, d) base rows — plus an optional (Nd, d)
     delta source — -> top-k (values, ids) in ONE kernel launch, never
-    materializing the (B, N) score matrix.
+    materializing the (B, N) score matrix, and ``rounds``: the top-k fold
+    rounds each block of ``bm`` queries ran, (cdiv(B, bm),) int32, at most
+    ``row_tiles(N, Nd, bn=bn) * min(k, bn)`` each.
 
     ``valid_n`` / ``delta_valid_n`` are TRACED scalars (rows at or past
     them are masked in-register); ``dead_mask`` / ``delta_dead_mask`` are
@@ -128,20 +130,22 @@ def streaming_fused_scan(q: jnp.ndarray, db: jnp.ndarray, k: int,
         ]
 
     grid = (Bp // bm, nbt + ndt, dp // bk)
-    vals, idxs = pl.pallas_call(
+    vals, idxs, rounds = pl.pallas_call(
         functools.partial(
             streaming_kernel, n_base_tiles=nbt, n_k_blocks=grid[2], bn=bn,
             k=k_eff, metric=metric, delta_id_offset=Nbp,
-            has_delta=has_delta),
+            has_delta=has_delta, n_queries=B),
         grid=grid,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((bm, k_eff), lambda i, j, kb: (i, 0)),
             pl.BlockSpec((bm, k_eff), lambda i, j, kb: (i, 0)),
+            pl.BlockSpec((8, 128), lambda i, j, kb: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Bp, k_eff), jnp.float32),
             jax.ShapeDtypeStruct((Bp, k_eff), jnp.int32),
+            jax.ShapeDtypeStruct((grid[0] * 8, 128), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
@@ -151,7 +155,13 @@ def streaming_fused_scan(q: jnp.ndarray, db: jnp.ndarray, k: int,
     vals, idxs = vals[:B], idxs[:B]
     order_vals, order_pos = jax.lax.top_k(vals, k_eff)
     idxs = jnp.take_along_axis(idxs, order_pos, axis=1)
-    return order_vals, idxs
+    return order_vals, idxs, rounds[::8, 0]
 
 
-__all__ = ["streaming_fused_scan"]
+def row_tiles(*n_rows: int, bn: int = 128) -> int:
+    """Row tiles one ``streaming_fused_scan`` visits per query block, for
+    row sources of ``n_rows`` rows each (base, then delta)."""
+    return sum(cdiv(n, bn) for n in n_rows)
+
+
+__all__ = ["row_tiles", "streaming_fused_scan"]

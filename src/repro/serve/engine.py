@@ -88,7 +88,7 @@ from repro.index.base import exact_topk
 from repro.kernels.common import EXACT, default_interpret
 from repro.kernels.distance.kernel import batched_scores
 from repro.kernels.distance.ops import fused_scan
-from repro.kernels.streaming.ops import streaming_fused_scan
+from repro.kernels.streaming.ops import row_tiles, streaming_fused_scan
 from repro.kernels.topk.kernel import NEG_INF
 from repro.obs import NULL_OBSERVER
 from repro.serve.columnstore import ColumnStore, DeviceColumn, row_sharding
@@ -232,8 +232,8 @@ def cache_probe_scan(qmat, mat, valid_n, interpret: bool | None = None):
     if interpret:
         vals, ids = _xla_cache_probe(qmat, mat, valid_n)
     else:
-        vals, ids = streaming_fused_scan(qmat, mat, k=1, metric="l2",
-                                         valid_n=valid_n, interpret=False)
+        vals, ids, _ = streaming_fused_scan(qmat, mat, k=1, metric="l2",
+                                            valid_n=valid_n, interpret=False)
     return np.asarray(vals), np.asarray(ids)
 
 
@@ -479,6 +479,19 @@ class BatchEngine:
             return [np.asarray(a) for a in arrays]
         with self.obs.span("fetch"):
             return [np.asarray(a) for a in arrays]
+
+    def _fetch_scan(self, vals, ids, rounds, tiles: int):
+        """``_fetch`` of one streaming scan's (vals, ids). When observed,
+        its fold ``rounds`` come in the same blocking read and are counted
+        against the ``tiles`` row tiles each query block visited: the
+        fold engages ``scan_fold_rounds / (scan_row_tiles · min(k, 128))``
+        of the time."""
+        if not self.obs.enabled:
+            return self._fetch(vals, ids)
+        vals, ids, rounds = self._fetch(vals, ids, rounds)
+        self.obs.counter("scan_fold_rounds", int(rounds.sum()))
+        self.obs.counter("scan_row_tiles", rounds.size * tiles)
+        return vals, ids
 
     def _run_group(self, group: PlanGroup, sq: dict | None = None):
         if group.key.pred is not None:
@@ -965,10 +978,12 @@ class BatchEngine:
             vals, ids = (step(col.data, qmat, bad) if bad is not None
                          else step(col.data, qmat))
         elif self.streaming:
-            vals, ids = streaming_fused_scan(
+            vals, ids, rounds = streaming_fused_scan(
                 qmat, col.data, k=min(k, col.n_rows), valid_n=col.n_rows,
                 dead_mask=dead_mask, keep_mask=keep_mask,
                 interpret=self.interpret)
+            return self._fetch_scan(vals, ids, rounds,
+                                    row_tiles(col.data.shape[0]))
         else:
             vals, ids = fused_scan(qmat, col.data, k=k, valid_n=col.n_rows,
                                    dead_mask=dead_mask, keep_mask=keep_mask,
@@ -1041,12 +1056,14 @@ class BatchEngine:
                  else fstate.delta_keep_dev(int(dcol.col.data.shape[0])))
         self.counters.scan += 1
         k_eff = min(depth, col.n_rows + dcol.n_rows)
-        vals, ids = streaming_fused_scan(
+        vals, ids, rounds = streaming_fused_scan(
             qmat, col.data, k=k_eff, valid_n=col.n_rows, dead_mask=dead,
             delta=dcol.col.data, delta_valid_n=dcol.n_rows,
             delta_dead_mask=dcol.dead_mask, keep_mask=bkeep,
             delta_keep_mask=dkeep, interpret=self.interpret)
-        vals, ids = self._fetch(vals, ids)
+        vals, ids = self._fetch_scan(
+            vals, ids, rounds,
+            row_tiles(col.data.shape[0], dcol.col.data.shape[0]))
         # combined-physical ids -> stable: delta rows are offset by the
         # PADDED base row count (the kernel's id space)
         base_pad_rows = int(col.data.shape[0])

@@ -79,6 +79,16 @@ def test_streaming_scan_masked_delta_l2(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < B * (N + nd) * 4
 
 
+def test_streaming_scan_k_past_row_tile(one_chip):
+    # IVF's gathered probe union: one query, k far past the 128-row tile,
+    # so the fold's data-dependent trip count is capped at the tile
+    d, k = 512, 1024
+    s = functools.partial(_spec, one_chip)
+    compiled = _scan(k=k).lower(
+        s((1, d)), s((N, d)), valid_n=s((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_semcache_probe_k1(one_chip):
     # the semantic cache's L2 probe: queries vs the cache's query ring
     cap, d = 256, 512
