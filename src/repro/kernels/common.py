@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 EXACT = jax.lax.Precision.HIGHEST
+LANE = 128   # lanes of a vreg, and the MXU's width on a v5e
 
 
 def default_interpret() -> bool:

@@ -484,8 +484,8 @@ class BatchEngine:
         """``_fetch`` of one streaming scan's (vals, ids). When observed,
         its fold ``rounds`` come in the same blocking read and are counted
         against the ``tiles`` row tiles each query block visited: the
-        fold engages ``scan_fold_rounds / (scan_row_tiles · min(k, 128))``
-        of the time."""
+        fold engages ``scan_fold_rounds / (scan_row_tiles · min(k, bn))``
+        of the time, bn the scan's row tile (``scan_tiles``)."""
         if not self.obs.enabled:
             return self._fetch(vals, ids)
         vals, ids, rounds = self._fetch(vals, ids, rounds)
@@ -982,8 +982,9 @@ class BatchEngine:
                 qmat, col.data, k=min(k, col.n_rows), valid_n=col.n_rows,
                 dead_mask=dead_mask, keep_mask=keep_mask,
                 interpret=self.interpret)
-            return self._fetch_scan(vals, ids, rounds,
-                                    row_tiles(col.data.shape[0]))
+            return self._fetch_scan(
+                vals, ids, rounds,
+                row_tiles(qmat, col.data, min(k, col.n_rows)))
         else:
             vals, ids = fused_scan(qmat, col.data, k=k, valid_n=col.n_rows,
                                    dead_mask=dead_mask, keep_mask=keep_mask,
@@ -1063,7 +1064,7 @@ class BatchEngine:
             delta_keep_mask=dkeep, interpret=self.interpret)
         vals, ids = self._fetch_scan(
             vals, ids, rounds,
-            row_tiles(col.data.shape[0], dcol.col.data.shape[0]))
+            row_tiles(qmat, col.data, k_eff, dcol.col.data))
         # combined-physical ids -> stable: delta rows are offset by the
         # PADDED base row count (the kernel's id space)
         base_pad_rows = int(col.data.shape[0])

@@ -8,6 +8,9 @@ must equal the two-dispatch merge for every index kind. The fast lane
 keeps smoke cases; the CI ``kernels`` job runs the whole file with
 ``-m ""`` so the slow grid is exercised on every PR.
 """
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -21,7 +24,10 @@ from repro.ingest import (DeleteBatch, InsertBatch, MutableTable,
                           MutationView, UpsertBatch)
 from repro.kernels.distance.kernel import batched_scores
 from repro.kernels.distance.ops import _mask_rows
-from repro.kernels.streaming.ops import row_tiles, streaming_fused_scan
+from repro.kernels.common import cdiv
+from repro.kernels.streaming import ops as scan_ops
+from repro.kernels.streaming.ops import (row_tiles, scan_tiles,
+                                         streaming_fused_scan)
 from repro.kernels.streaming.ref import streaming_fused_scan_ref
 from repro.kernels.topk.kernel import NEG_INF, neg_inf_for, topk_scores
 from repro.obs import NULL_OBSERVER, Observer
@@ -31,9 +37,12 @@ from repro.serve.engine import BatchEngine
 
 # ---- kernel-level parity grid ---------------------------------------------
 
-# ragged shape cases: N not a multiple of the 128 row tile, valid_n < N,
+# ragged shape cases: N not a multiple of the row tile, valid_n < N,
 # k > live rows, all rows dead, B == 1, and B == max dispatch batch —
-# with and without the delta second source
+# with and without the delta second source; B of 5, 8 and 32 (a query
+# block under 128 rows), N ragged against a 384-row tile, a base of whole
+# 128-row blocks (delta ids offset by exactly N) with a delta longer than
+# one tile, k past the tile (IVF), and k = 1 over a ring shorter than a tile
 CASES = {
     "ragged_n": dict(B=4, N=300, d=48, k=20),
     "pad_and_dead": dict(B=17, N=384, d=100, k=25, valid_n=260, n_dead=30),
@@ -43,7 +52,39 @@ CASES = {
                      delta=dict(N=70, valid_n=60, n_dead=5)),
     "maxbatch_delta": dict(B=128, N=256, d=64, k=10,
                            delta=dict(N=40, n_dead=0)),
+    "b5_ragged_tile": dict(B=5, N=1000, d=40, k=30, valid_n=990, n_dead=50),
+    "b8_delta_offset": dict(B=8, N=640, d=64, k=50, n_dead=20,
+                            delta=dict(N=500, valid_n=490, n_dead=10)),
+    "b32_k_past_tile": dict(B=32, N=700, d=32, k=400, valid_n=650),
+    "b1_ring_k1": dict(B=1, N=200, d=48, k=1, valid_n=150),
 }
+
+# block shapes: the op's own (``scan_tiles``), the parent's 128 × 128,
+# and a 384-row tile that leaves most CASES a ragged last tile
+TILES = {"own": None, "parent128": (128, 128), "ragged384": (None, 384)}
+
+_tiled_scan = jax.jit(scan_ops._scan, static_argnames=(
+    "k", "metric", "bm", "bn", "interpret"))
+
+
+def _scan(q, db, k, tiles=None, **kw):
+    """The op at its own tiles, or the same launch at (bm, bn) ``tiles``
+    (bm None: the op's own for this batch)."""
+    if tiles is None:
+        return streaming_fused_scan(q, db, k=k, interpret=True, **kw)
+    bm, bn = tiles
+    bm = bm or scan_tiles(q.shape[0], db.shape[0], db.shape[1], k)[0]
+    return _tiled_scan(q, db, k, bm=bm, bn=bn, interpret=True, **kw)
+
+
+def _n_tiles(q, db, k, tiles=None, delta=None):
+    """(bn, row tiles a query block visits) of ``_scan`` at ``tiles``."""
+    if tiles is None:
+        bn = scan_ops._tiles(q, db, k, delta)[1]
+        return bn, row_tiles(q, db, k, delta)
+    bn = tiles[1]
+    return bn, cdiv(db.shape[0], bn) + (0 if delta is None
+                                        else cdiv(delta.shape[0], bn))
 
 
 def _mk(rng, n, d, dtype):
@@ -60,10 +101,11 @@ def _dead(rng, n, n_dead):
     return jnp.asarray(m)
 
 
-def _assert_bit_identical(case, metric, dtype, seed=0):
+def _assert_bit_identical(case, metric, dtype, seed=0, tiles=None):
     rng = np.random.default_rng(seed)
     q = _mk(rng, case["B"], case["d"], dtype)
     db = _mk(rng, case["N"], case["d"], dtype)
+    db_host = np.asarray(db)
     kw = dict(valid_n=case.get("valid_n"),
               dead_mask=_dead(rng, case["N"], case.get("n_dead")))
     dl = case.get("delta")
@@ -71,17 +113,28 @@ def _assert_bit_identical(case, metric, dtype, seed=0):
         kw.update(delta=_mk(rng, dl["N"], case["d"], dtype),
                   delta_valid_n=dl.get("valid_n"),
                   delta_dead_mask=_dead(rng, dl["N"], dl.get("n_dead")))
-    vals, ids, _ = streaming_fused_scan(q, db, k=case["k"], metric=metric,
-                                        interpret=True, **kw)
+    vals, ids, _ = _scan(q, db, case["k"], tiles, metric=metric, **kw)
+    np.testing.assert_array_equal(np.asarray(db), db_host)   # untouched
     rvals, rids = streaming_fused_scan_ref(q, db, k=case["k"], metric=metric,
                                            interpret=True, **kw)
     np.testing.assert_array_equal(np.asarray(vals), np.asarray(rvals))
     np.testing.assert_array_equal(np.asarray(ids), np.asarray(rids))
 
 
-@pytest.mark.parametrize("name", ["pad_and_dead", "b1_delta"])
-def test_streaming_parity_smoke(name):
-    _assert_bit_identical(CASES[name], "dot", jnp.float32)
+@pytest.mark.parametrize("tiles", sorted(TILES))
+@pytest.mark.parametrize("name", ["pad_and_dead", "b1_delta",
+                                  "b5_ragged_tile", "b8_delta_offset",
+                                  "b32_k_past_tile"])
+def test_streaming_parity_smoke(name, tiles):
+    _assert_bit_identical(CASES[name], "dot", jnp.float32,
+                          tiles=TILES[tiles])
+
+
+@pytest.mark.parametrize("tiles", sorted(TILES))
+def test_streaming_ring_probe_k1_l2(tiles):
+    """The semantic cache's probe: k = 1, l2, a ring shorter than a tile."""
+    _assert_bit_identical(CASES["b1_ring_k1"], "l2", jnp.float32,
+                          tiles=TILES[tiles])
 
 
 @pytest.mark.slow  # full interpret-mode grid; CI kernels job runs it
@@ -91,6 +144,66 @@ def test_streaming_parity_smoke(name):
 def test_streaming_parity_grid(name, metric, dtype):
     _assert_bit_identical(CASES[name], metric, dtype,
                           seed=abs(hash(name)) % 1000)
+
+
+# ---- the block shape ---------------------------------------------------------
+
+
+@pytest.mark.parametrize("d", [100, 128, 256, 384, 512, 768, 1024, 2560])
+def test_scan_tiles_fit_vmem_budget(d):
+    """A query block of the batch's real rows (rounded up to the sublane
+    tile, at most 128) and a row tile of whole lanes whose blocks fit the
+    VMEM budget, never past N rounded up to 128; at the chip cell's
+    shape (1,000,064 rows, k 100, B ≤ 32) at least 1,024 rows."""
+    dp = -(-d // 128) * 128
+    for B in (1, 5, 8, 17, 32, 64, 128, 200):
+        for n in (3, 200, 1000, 8192, 1_000_064):
+            for k in (1, 100, 1024):
+                for itemsize, sources in ((4, 1), (4, 2), (2, 1)):
+                    bm, bn = scan_tiles(B, n, d, k, itemsize, sources)
+                    sublane = 8 * (4 // itemsize)
+                    assert bm == min(128, -(-B // sublane) * sublane)
+                    assert bn % 128 == 0 and bn >= 128
+                    assert bn == 128 or bm * bn <= scan_ops.MAX_TILE
+                    assert bn <= -(-n // 128) * 128
+                    assert bn == 128 or scan_ops._vmem_bytes(
+                        bm, bn, dp, k, itemsize, sources
+                    ) <= scan_ops.VMEM_BUDGET
+                    if n == 1_000_064 and k == 100 and B <= 32 and dp <= 512:
+                        assert bn >= 1024
+
+
+def _pallas_operands(fn, *args):
+    """(the jitted wrapper's inputs, the pallas_call's operands) in the
+    jaxpr ``fn`` traces to."""
+    outer = jax.make_jaxpr(fn)(*args).jaxpr
+    (wrapper,) = [e for e in outer.eqns if "jaxpr" in e.params]
+    inner = wrapper.params["jaxpr"].jaxpr
+    (call,) = [e for e in inner.eqns if e.primitive.name == "pallas_call"]
+    return inner.invars, call.invars
+
+
+@pytest.mark.parametrize("n_delta", [0, 20_000, 40],
+                         ids=["base", "long_delta", "short_delta"])
+def test_scan_reads_column_uncopied(n_delta):
+    """The kernel reads the 1,000,064-row column as passed, though its
+    last tile is ragged: no pad or copy of its rows on a call. A delta
+    longer than a tile is read as passed too; one shorter is padded to
+    a tile (a copy of at most bn rows)."""
+    S = jax.ShapeDtypeStruct
+    q, db = S((1, 128), jnp.float32), S((1_000_064, 128), jnp.float32)
+    bn = scan_tiles(1, db.shape[0], 128, 100, 4, 2 if n_delta else 1)[1]
+    assert db.shape[0] % bn != 0                        # ragged
+    if not n_delta:
+        fn = functools.partial(streaming_fused_scan, k=100, interpret=True)
+        ins, ops_in = _pallas_operands(fn, q, db)
+    else:
+        dlt = S((n_delta, 128), jnp.float32)
+        fn = functools.partial(streaming_fused_scan, k=100, interpret=True)
+        ins, ops_in = _pallas_operands(
+            lambda q, db, dlt: fn(q, db, delta=dlt), q, db, dlt)
+        assert (ops_in[2] is ins[2]) == (n_delta >= bn)
+    assert ops_in[1] is ins[1]
 
 
 def test_streaming_all_dead_tail_contract():
@@ -108,7 +221,7 @@ def test_streaming_all_dead_tail_contract():
 
 # ---- the gated fold: only the rounds a row tile can win --------------------
 
-GATE_B, GATE_N, GATE_D, GATE_BN = 5, 1024, 32, 128   # B not a multiple of 8
+GATE_B, GATE_N, GATE_D = 5, 1024, 32   # B not a multiple of 8
 
 
 def _gate_rows(rng, n, order):
@@ -131,10 +244,11 @@ def _gate_rows(rng, n, order):
     return q, rows
 
 
-def _gate_scan(metric, order, k, masked, seed=0):
-    """Gated op and two-pass oracle on one ``_gate_rows`` table; masked
-    adds tombstones, a keep mask, a valid_n cut and a delta source that
-    continues the base's order."""
+def _gate_scan(metric, order, k, masked, seed=0, tiles=None):
+    """Gated op (at ``tiles``, see ``_scan``) and two-pass oracle on one
+    ``_gate_rows`` table; masked adds tombstones, a keep mask, a valid_n
+    cut and a delta source that continues the base's order. Also the
+    op's (bn, row tiles a query block visits)."""
     rng = np.random.default_rng(seed)
     n_delta = 256 if masked else 0
     q, rows = _gate_rows(rng, GATE_N + n_delta, order)
@@ -147,33 +261,38 @@ def _gate_scan(metric, order, k, masked, seed=0):
                   delta=rows[GATE_N:], delta_valid_n=n_delta - 10,
                   delta_dead_mask=jnp.asarray(rng.random(n_delta) < 0.1),
                   delta_keep_mask=jnp.asarray(rng.random(n_delta) < 0.8))
-    got = streaming_fused_scan(q, rows[:GATE_N], k=k, metric=metric,
-                               interpret=True, **kw)
+    got = _scan(q, rows[:GATE_N], k, tiles, metric=metric, **kw)
     ref = streaming_fused_scan_ref(q, rows[:GATE_N], k=k, metric=metric,
                                    interpret=True, **kw)
-    return [np.asarray(a) for a in got], [np.asarray(a) for a in ref]
+    shape = _n_tiles(q, rows[:GATE_N], k, tiles, kw.get("delta"))
+    return ([np.asarray(a) for a in got], [np.asarray(a) for a in ref],
+            shape)
 
 
+@pytest.mark.parametrize("tiles", sorted(TILES))
 @pytest.mark.parametrize("order", ["random", "ascending", "descending"])
 @pytest.mark.parametrize("masked", [False, True], ids=["plain", "masked_delta"])
 @pytest.mark.parametrize("metric", ["dot", "cosine", "l2"])
-def test_gated_fold_bit_identical(metric, masked, order):
-    (vals, ids, _), (rvals, rids) = _gate_scan(metric, order, 20, masked)
+def test_gated_fold_bit_identical(metric, masked, order, tiles):
+    (vals, ids, _), (rvals, rids), _ = _gate_scan(metric, order, 20, masked,
+                                                  tiles=TILES[tiles])
     np.testing.assert_array_equal(vals, rvals)
     np.testing.assert_array_equal(ids, rids)
 
 
+@pytest.mark.parametrize("tiles", ["parent128", "ragged384"])
 @pytest.mark.parametrize("order", ["random", "ascending", "descending"])
-def test_gated_fold_k_past_row_tile(order):
-    """k = 200 > bn: a tile runs at most bn rounds, and still every one
+def test_gated_fold_k_past_row_tile(order, tiles):
+    """k = 400 > bn: a tile runs at most bn rounds, and still every one
     the k-round fold would have used."""
-    (vals, ids, rounds), (rvals, rids) = _gate_scan("l2", order, 200, False)
+    (vals, ids, rounds), (rvals, rids), (bn, n) = _gate_scan(
+        "l2", order, 400, False, tiles=TILES[tiles])
     np.testing.assert_array_equal(vals, rvals)
     np.testing.assert_array_equal(ids, rids)
-    assert rounds.max() <= row_tiles(GATE_N) * GATE_BN
+    assert rounds.max() <= n * bn
 
 
-def _k_round_fold(scores, k, bn=GATE_BN):
+def _k_round_fold(scores, k, bn):
     """The fold without its gate, on the host: k rounds in every row tile
     of a (B, N) score matrix (first max of the tile against the first
     slot holding the buffer's least value, strict improvement only), then
@@ -197,23 +316,25 @@ def _k_round_fold(scores, k, bn=GATE_BN):
             np.take_along_axis(idxs, order, 1))
 
 
+@pytest.mark.parametrize("tiles", sorted(TILES))
 @pytest.mark.parametrize("metric", ["dot", "cosine", "l2"])
-def test_gated_fold_ties_bit_identical(metric):
+def test_gated_fold_ties_bit_identical(metric, tiles):
     """Every row twice, within and across tiles. The gated op equals the
-    k-round fold bit for bit, tie order included; against the two-pass
-    oracle the values match bit for bit and the same ids win, while the
-    order among equal scores follows each path's own buffer (bn 128 here,
-    512 there) as it did before the gate."""
+    k-round fold over its own row tiles bit for bit, tie order included;
+    against the two-pass oracle the values match bit for bit and the same
+    ids win, while the order among equal scores follows each path's own
+    buffer (the op's bn here, 512 there) as it did before the gate."""
     rng = np.random.default_rng(7)
     q, rows = _gate_rows(rng, GATE_N, "random")
     rows[1::2] = rows[::2]
     rows[GATE_N // 2:] = rows[:GATE_N // 2]
     q, rows = jnp.asarray(q), jnp.asarray(rows)
-    vals, ids, _ = [np.asarray(a) for a in streaming_fused_scan(
-        q, rows, k=20, metric=metric, interpret=True)]
+    vals, ids, _ = [np.asarray(a) for a in _scan(
+        q, rows, 20, TILES[tiles], metric=metric)]
     scores = np.asarray(batched_scores(q, rows, metric=metric, bk=128,
                                        interpret=True))
-    mvals, mids = _k_round_fold(scores, 20)
+    bn, _ = _n_tiles(q, rows, 20, TILES[tiles])
+    mvals, mids = _k_round_fold(scores, 20, bn)
     np.testing.assert_array_equal(vals, mvals)
     np.testing.assert_array_equal(ids, mids)
     rvals, rids = [np.asarray(a) for a in streaming_fused_scan_ref(
@@ -223,26 +344,32 @@ def test_gated_fold_ties_bit_identical(metric):
         assert sorted(zip(-vals[b], ids[b])) == sorted(zip(-rvals[b], rids[b]))
 
 
+@pytest.mark.parametrize("tiles", sorted(TILES))
 @pytest.mark.parametrize("k", [20, 200])
 @pytest.mark.parametrize("order", ["ascending", "descending"])
 @pytest.mark.parametrize("metric", ["dot", "cosine", "l2"])
-def test_fold_rounds_exact(metric, order, k):
-    """Ascending rows: every tile wins min(k, bn) rounds. Descending: only
-    the tiles that fill the buffer fold. Under l2 the padding query rows'
-    scores rise along a descending table: they must not open the gate."""
-    (_, _, rounds), _ = _gate_scan(metric, order, k, False)
-    per_tile = min(k, GATE_BN)
-    tiles = row_tiles(GATE_N)
-    want = tiles * per_tile if order == "ascending" else -(-k // GATE_BN) * per_tile
+def test_fold_rounds_exact(metric, order, k, tiles):
+    """Ascending rows: every tile wins min(k, bn) rounds (the ragged last
+    tile of ragged384 keeps 256 live rows, past either k). Descending:
+    only the tiles that fill the buffer fold. Under l2 the padding query
+    rows' scores rise along a descending table: they must not open the
+    gate."""
+    (_, _, rounds), _, (bn, n) = _gate_scan(metric, order, k, False,
+                                            tiles=TILES[tiles])
+    per_tile = min(k, bn)
+    want = n * per_tile if order == "ascending" else -(-k // bn) * per_tile
     assert rounds.tolist() == [want]
 
 
+@pytest.mark.parametrize("tiles", sorted(TILES))
 @pytest.mark.parametrize("masked", [False, True], ids=["plain", "masked_delta"])
-def test_fold_rounds_bounded(masked):
-    (_, _, rounds), _ = _gate_scan("dot", "random", 20, masked, seed=3)
-    tiles = row_tiles(GATE_N, 256 if masked else 0)
+def test_fold_rounds_bounded(masked, tiles):
+    """At most min(k, bn) rounds in each row tile a query block visits:
+    for the op's own tiles, ``row_tiles`` counts them."""
+    (_, _, rounds), _, (bn, n) = _gate_scan("dot", "random", 20, masked,
+                                            seed=3, tiles=TILES[tiles])
     assert rounds.shape == (1,)
-    assert 20 <= rounds[0] <= tiles * 20   # the first tile always folds
+    assert 20 <= rounds[0] <= n * min(20, bn)   # the first tile always folds
 
 
 # ---- satellite: per-dtype top-k sentinel -----------------------------------
@@ -418,8 +545,7 @@ def _spy_scans(monkeypatch):
     def spy(q, db, *a, **kw):
         out = streaming_fused_scan(q, db, *a, **kw)
         delta = kw.get("delta")
-        tiles = row_tiles(db.shape[0],
-                          *(() if delta is None else (delta.shape[0],)))
+        tiles = row_tiles(q, db, kw["k"], delta)
         seen.append((int(np.asarray(out[2]).sum()), out[2].size * tiles,
                      delta is not None))
         return out

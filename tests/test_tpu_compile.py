@@ -15,7 +15,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.index.ivf import _lloyd
-from repro.kernels.streaming.ops import streaming_fused_scan
+from repro.kernels.streaming.ops import scan_tiles, streaming_fused_scan
 from repro.launch.roofline import V5E, peaks
 from repro.serve.engine import _GATHER_BYTES, _gather_scores, gather_chunk
 
@@ -66,6 +66,22 @@ def test_streaming_scan_plain(one_chip, d):
     assert compiled.memory_analysis().temp_size_in_bytes < B * N * 4
 
 
+@pytest.mark.parametrize("batch", [1, 32])
+@pytest.mark.parametrize("d", [128, 512])
+def test_streaming_scan_chip_cell(one_chip, d, batch):
+    # the benchmark cell's flat scan: a resident 1M-row column (rows
+    # padded to 128) at its own tiles, with a ragged last tile; the
+    # column is read in place, never copied
+    n = 1_000_064
+    bm, bn = scan_tiles(batch, n, d, 100)
+    assert n % bn and bn >= 1024 and bm == max(8, batch)
+    s = functools.partial(_spec, one_chip)
+    compiled = _scan(k=100).lower(
+        s((batch, d)), s((n, d)), valid_n=s((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < n * d * 4
+
+
 def test_streaming_scan_masked_delta_l2(one_chip):
     d, nd = 768, 8192
     s = functools.partial(_spec, one_chip)
@@ -80,8 +96,8 @@ def test_streaming_scan_masked_delta_l2(one_chip):
 
 
 def test_streaming_scan_k_past_row_tile(one_chip):
-    # IVF's gathered probe union: one query, k far past the 128-row tile,
-    # so the fold's data-dependent trip count is capped at the tile
+    # IVF's gathered probe union: one query (an 8-row query block), k far
+    # past a 128-row tile, so the fold's trip count is capped at min(k, bn)
     d, k = 512, 1024
     s = functools.partial(_spec, one_chip)
     compiled = _scan(k=k).lower(
